@@ -98,9 +98,7 @@ def main() -> dict:
     jax.block_until_ready(linalg.matmul(A, B, tuner=tuner))  # warm compile
     total = _best_of(lambda: jax.block_until_ready(
         linalg.matmul(A, B, tuner=tuner)))
-    devs = disp._resolve(devices, plan.p)
-    mesh = disp._mesh_for(plan.g, plan.c, devs)
-    fn = disp._executor(plan, mesh, devs, interpret=True)
+    fn, mesh = disp.executor(plan, devices)
     from jax.sharding import PartitionSpec as P
     m = disp._round_up(n, plan.g)
     Ad = linalg.distribute(disp._pad_zero(A, m, m), mesh, P("row", "col"))

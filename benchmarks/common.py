@@ -71,10 +71,25 @@ def emit(name: str, us_per_call: float, derived: str = ""):
     print(f"{name},{us_per_call:.3f},{derived}", flush=True)
 
 
+class BenchSkipped(Exception):
+    """A bench that cannot run in this process; the message says why."""
+
+
 def run_subprocess_bench(module: str, n_devices: int = 8,
                          timeout: int = 560) -> dict:
     """Run `python -m {module}` with forced host devices; the module prints
-    a single JSON object on its last stdout line."""
+    a single JSON object on its last stdout line.
+
+    These benches are CPU rehearsals.  On an accelerator host the calling
+    process holds the chip, and a child that starts JAX there cannot open
+    it (one process per chip), so the bench is skipped instead."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise BenchSkipped(
+            f"{module} runs on forced CPU host devices in a child process; "
+            f"this process holds the {backend} (one process per chip)")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={n_devices}").strip()

@@ -4,8 +4,11 @@ roofline/dry-run aggregates.  Prints ``name,us_per_call,derived`` CSV.
     PYTHONPATH=src python -m benchmarks.run [--only fig1 tables ...]
 
 Multi-device benches run in subprocesses with their own
---xla_force_host_platform_device_count (the main process stays 1-device).
-Results are also written to artifacts/bench/*.json.
+--xla_force_host_platform_device_count (the main process stays 1-device);
+they are CPU rehearsals, skipped on an accelerator host, where this
+process holds the chip.  JAX's persistent compilation cache is on
+(``repro.compile_cache``).  Results are also written to
+artifacts/bench/*.json.
 """
 
 import argparse
@@ -17,8 +20,8 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 
-from benchmarks.common import (ART, emit, run_meta,  # noqa: E402
-                               run_subprocess_bench)
+from benchmarks.common import (ART, BenchSkipped, emit,  # noqa: E402
+                               run_meta, run_subprocess_bench)
 
 OUT = os.path.join(ART, "bench")
 
@@ -297,6 +300,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.check_regressions:
         sys.exit(check_regressions())
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     for name, fn in BENCHES.items():
@@ -304,6 +309,8 @@ def main() -> None:
             continue
         try:
             fn()
+        except BenchSkipped as e:
+            emit(f"{name}_SKIPPED", 0.0, str(e))
         except Exception as e:  # noqa: BLE001
             failures.append((name, repr(e)))
             emit(f"{name}_FAILED", 0.0, repr(e)[:120])

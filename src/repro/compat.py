@@ -1,10 +1,8 @@
-"""Version-portability shims for the JAX APIs this repo uses.
+"""Mesh construction shared by the executors and launchers.
 
-The codebase is written against the modern spellings (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``lax.pcast``); this module maps
-them onto older releases (0.4.x) where they live under ``jax.experimental``
-or do not exist yet.  Import from here instead of feature-testing at each
-call site.
+Written against the installed JAX (0.9), where ``jax.make_mesh`` takes
+explicit axis types; the executors call ``jax.shard_map`` and
+``lax.pcast`` directly.
 """
 
 from __future__ import annotations
@@ -12,44 +10,12 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from jax import lax
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                                 axis_types=(axis_type.Auto,) * len(axis_shapes),
-                                 devices=devices)
-        except TypeError:
-            pass
+    """``jax.make_mesh`` with every axis Auto."""
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_shapes),
                          devices=devices)
-
-
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:  # jax < 0.5: experimental module, and no pcast-aware rep checker
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        del check_vma  # the old rep checker predates varying-marking
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
-
-def pcast_varying(x, axes):
-    """Mark ``x`` as varying over ``axes`` for the replication checker.
-
-    A no-op on releases without ``lax.pcast`` — there the checker that
-    needs the marking does not exist either (shard_map runs check_rep=False).
-    """
-    pcast = getattr(lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axes, to="varying")

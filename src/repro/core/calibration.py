@@ -195,8 +195,8 @@ def bench_contention(n_procs: int, distance: int, words: int = 1 << 20,
         perm = [(i, (i + distance) % n_procs) for i in range(n_procs)]
         return jax.lax.ppermute(x, "x", perm)
 
-    run = jax.jit(compat.shard_map(shift, mesh=mesh, in_specs=P("x"),
-                                   out_specs=P("x")))
+    run = jax.jit(jax.shard_map(shift, mesh=mesh, in_specs=P("x"),
+                                out_specs=P("x")))
 
     x = jnp.ones((n_procs * words,), dtype)
     xs = jax.device_put(x, NamedSharding(mesh, P("x")))

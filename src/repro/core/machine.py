@@ -19,6 +19,8 @@ import hashlib
 import json
 from typing import Optional
 
+from ..perf.kernel import VMEM_BUDGET
+
 
 @dataclasses.dataclass(frozen=True)
 class KernelConstants:
@@ -144,7 +146,7 @@ TPU_V5E = Machine(
         bw_h2d=819e9, bw_d2h=410e9,
         c_h2d=2e-6, c_d2h=5e-6,
         overhead_factor=1.35, loop_overhead=1.5e-6,
-        vmem_bytes=96 * 1024 * 1024),
+        vmem_bytes=VMEM_BUDGET),
 )
 
 # ---------------------------------------------------------------------------
@@ -175,7 +177,7 @@ CPU_HOST = Machine(
         bw_h2d=8e9, bw_d2h=4e9,
         c_h2d=2e-4, c_d2h=2e-4,
         overhead_factor=2.0, loop_overhead=5e-4,
-        vmem_bytes=96 * 1024 * 1024),
+        vmem_bytes=VMEM_BUDGET),
 )
 
 MACHINES = {m.name: m for m in (HOPPER, TPU_V5E, CPU_HOST)}

@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the framework's compute hot spots.
 
 Each kernel ships three files: the pallas_call + BlockSpec kernel, ops.py
-(jit'd public wrapper, interpret=True default for CPU validation), and
-ref.py (pure-jnp oracle used by the allclose test sweeps).
+(jit'd public wrapper: the Pallas interpreter off the TPU, the compiled
+kernel on it), and ref.py (pure-jnp oracle used by the allclose test
+sweeps).
 """
 
 from .common import TilePlan, heuristic_plan, pad_axes, round_up

@@ -6,6 +6,13 @@ as with dtrsm — the kernel factors only a VMEM-resident diagonal block
 full factorization so panel solves and trailing (syrk) updates run through
 the trsm/matmul kernels on the MXU.
 
+Like LAPACK's dpotrf the kernel reads only the lower triangle: it mirrors
+it into an exactly symmetric f32 working block, so column k and row k of
+the trailing matrix are the same numbers and the rank-1 update needs no
+transpose.  Column k and row k are picked out with iota masks and exact
+masked reductions (no dynamic slice); the scaled column overwrites column
+k of the working block, whose lower triangle then holds L.
+
 One grid step per call (the block is the whole problem for the kernel).
 """
 
@@ -17,27 +24,37 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..common import compiler_params
 
 
-def _chol_kernel(a_ref, l_ref, *, nb: int):
+def _chol_kernel(a_ref, l_ref, w_ref, *, nb: int):
     a = a_ref[...].astype(jnp.float32)
-    row = lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
+    row = lax.broadcasted_iota(jnp.int32, (nb, nb), 0)
+    col = lax.broadcasted_iota(jnp.int32, (nb, nb), 1)
+    w_ref[...] = jnp.where(row >= col, a, a.T)
 
-    def body(k, a):
-        akk = lax.dynamic_slice(a, (k, k), (1, 1))
-        d = jnp.sqrt(akk)
-        col = lax.dynamic_slice(a, (0, k), (nb, 1)) / d
-        col = jnp.where(row >= k, col, jnp.zeros_like(col))  # col[k] = d
-        a = lax.dynamic_update_slice(a, col, (0, k))
-        # trailing rank-1 update; (col*mask)[k] == 0 keeps column k intact
-        colm = jnp.where(row > k, col, jnp.zeros_like(col))
-        a = a - jnp.dot(colm, colm.T, preferred_element_type=jnp.float32)
-        return a
+    def body(k, carry):
+        row = lax.broadcasted_iota(jnp.int32, (nb, nb), 0)
+        col = lax.broadcasted_iota(jnp.int32, (nb, nb), 1)
+        sub = lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
+        lane = lax.broadcasted_iota(jnp.int32, (1, nb), 1)
+        w = w_ref[...]
+        colk = jnp.sum(jnp.where(col == k, w, 0.0), axis=1, keepdims=True)
+        rowk = jnp.sum(jnp.where(row == k, w, 0.0), axis=0, keepdims=True)
+        d = jnp.sqrt(jnp.sum(jnp.where(sub == k, colk, 0.0), axis=0,
+                             keepdims=True))                    # (1, 1)
+        lcol = colk / d
+        lrow = rowk / d
+        # trailing rank-1 update on rows and columns > k only
+        upd = w - jnp.where(sub > k, lcol, 0.0) * jnp.where(lane > k, lrow,
+                                                             0.0)
+        w_ref[...] = jnp.where(col == k, jnp.where(sub >= k, lcol, 0.0), upd)
+        return carry
 
-    a = lax.fori_loop(0, nb, body, a)
-    colj = lax.broadcasted_iota(jnp.int32, (nb, nb), 1)
-    rowi = lax.broadcasted_iota(jnp.int32, (nb, nb), 0)
-    l_ref[...] = jnp.where(rowi >= colj, a, jnp.zeros_like(a)).astype(l_ref.dtype)
+    lax.fori_loop(0, nb, body, 0)
+    l_ref[...] = jnp.where(row >= col, w_ref[...], 0.0).astype(l_ref.dtype)
 
 
 def cholesky_block_pallas(a: jax.Array, *, interpret: bool = False) -> jax.Array:
@@ -50,5 +67,7 @@ def cholesky_block_pallas(a: jax.Array, *, interpret: bool = False) -> jax.Array
         in_specs=[pl.BlockSpec((nb, nb), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((nb, nb), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, nb), a.dtype),
+        scratch_shapes=[pltpu.VMEM((nb, nb), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(a)

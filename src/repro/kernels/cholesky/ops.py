@@ -1,6 +1,7 @@
 """Blocked Cholesky (right-looking) composed from all three linalg kernels:
 diagonal factor (cholesky kernel), panel solve (trsm kernel: L_ij L_jj^T =
-A_ij), trailing syrk update (matmul kernel)."""
+A_ij), trailing syrk update (matmul kernel).  A is identity-extended to
+the block, so every shape runs through the kernels."""
 
 from __future__ import annotations
 
@@ -10,17 +11,18 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..common import TilePlan, tile_block
+from ..common import (MIN_TILE, TilePlan, pad_eye, resolve_interpret,
+                      round_up, tile_block)
 from ..matmul.ops import matmul
 from ..trsm.ops import trsm
 from .cholesky import cholesky_block_pallas
-from .ref import cholesky_ref
 
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "block", "tiles",
                                     "mm_tiles"))
-def cholesky(a: jax.Array, *, block: int = 256, interpret: bool = True,
+def cholesky(a: jax.Array, *, block: int = 256,
+             interpret: Optional[bool] = None,
              tiles: Optional[TilePlan] = None,
              mm_tiles: Optional[TilePlan] = None) -> jax.Array:
     """L with L L^T = A (A SPD, (n, n)).
@@ -28,13 +30,14 @@ def cholesky(a: jax.Array, *, block: int = 256, interpret: bool = True,
     ``tiles`` (a cholesky :class:`TilePlan`, dim ``block``) overrides the
     panel width (the panel trsm necessarily solves at that width);
     ``mm_tiles`` is threaded to the dgemm-shaped trailing updates.
+    ``interpret`` defaults to the platform (see ``resolve_interpret``).
     """
+    interpret = resolve_interpret(interpret)
     block = tile_block(tiles, "cholesky", "block", block)
+    n0 = a.shape[0]
+    block = min(block, round_up(n0, MIN_TILE))
+    a = pad_eye(a, round_up(n0, block))
     n = a.shape[0]
-    if n % block != 0 or n <= block:
-        if n <= block and n >= 8:
-            return cholesky_block_pallas(a, interpret=interpret)
-        return cholesky_ref(a)
     nb = n // block
     acc = a
     l_cols = []
@@ -58,4 +61,4 @@ def cholesky(a: jax.Array, *, block: int = 256, interpret: bool = True,
             col = ljj
         col_full = jnp.pad(col, ((jj, 0), (0, 0)))
         l_cols.append(col_full)
-    return jnp.concatenate(l_cols, axis=1)
+    return jnp.concatenate(l_cols, axis=1)[:n0, :n0]

@@ -16,15 +16,31 @@ from typing import Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 # the model layer is pure numpy — importing it pulls no jax machinery in
 from ..perf.kernel import (MIN_TILE, TilePlan, VMEM_BUDGET,
                            heuristic_matmul_blocks, heuristic_plan)
 
 __all__ = [
-    "MIN_TILE", "TilePlan", "VMEM_BUDGET", "heuristic_matmul_blocks",
-    "heuristic_plan", "pad_axes", "round_up", "tile_block",
+    "MIN_TILE", "TilePlan", "VMEM_BUDGET", "compiler_params",
+    "heuristic_matmul_blocks", "heuristic_plan", "pad_axes",
+    "pad_eye", "resolve_interpret", "round_up", "tile_block",
 ]
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``interpret`` as given, else derived from the platform: the Pallas
+    interpreter off the TPU, the compiled kernel on it."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
+def compiler_params() -> pltpu.CompilerParams:
+    """Mosaic parameters of the linalg kernels: the scoped-VMEM limit is
+    the same ``VMEM_BUDGET`` the tile model plans against."""
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET)
 
 
 def round_up(x: int, m: int) -> int:
@@ -50,6 +66,18 @@ def pad_axes(x: jax.Array,
     if not any_pad:
         return x
     return jnp.pad(x, width)
+
+
+def pad_eye(x: jax.Array, size: int) -> jax.Array:
+    """blockdiag(x, I) of edge ``size``: the structure-preserving pad for
+    triangular and SPD operands (the padded system's solution is the
+    original one, zero-extended)."""
+    n = x.shape[0]
+    if size == n:
+        return x
+    out = jnp.pad(x, ((0, size - n), (0, size - n)))
+    idx = jnp.arange(n, size)
+    return out.at[idx, idx].set(jnp.ones((), x.dtype))
 
 
 def tile_block(tiles: Optional[TilePlan], kernel: str, dim: str,

@@ -8,14 +8,14 @@ from typing import Optional
 
 import jax
 
-from ..common import TilePlan, pad_axes, tile_block
+from ..common import TilePlan, pad_axes, resolve_interpret, tile_block
 from .flash_attention import flash_attention_pallas
 from .ref import flash_attention_ref
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret", "tiles"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, interpret: bool = True,
+                    causal: bool = True, interpret: Optional[bool] = None,
                     tiles: Optional[TilePlan] = None) -> jax.Array:
     """q: (B, H, S, D); k, v: (B, KV, S, D).  Returns (B, H, S, D).
 
@@ -47,5 +47,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out = flash_attention_pallas(
         qp.reshape(b * h, sp, dp), kp.reshape(b * kv, skvp, dp),
         vp.reshape(b * kv, skvp, dp), causal=causal, scale=scale,
-        bq=bq, bkv=bkv, kv_len=skv, interpret=interpret)
+        bq=bq, bkv=bkv, kv_len=skv,
+        interpret=resolve_interpret(interpret))
     return out.reshape(b, h, sp, dp)[:, :, :s, :d]

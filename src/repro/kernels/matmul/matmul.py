@@ -7,7 +7,9 @@ once, on the last K step (revisiting semantics).
 
 Block sizes default to (256, 256, 512): A-block 256x512 + B-block 512x256
 bf16 = 0.5 MB and the f32 accumulator 0.25 MB comfortably fit VMEM while
-keeping every matmul dimension a multiple of the 128x128 MXU tile.
+keeping every matmul dimension a multiple of the 128x128 MXU tile.  The
+kernel is compiled under the scoped-VMEM limit the tile model plans
+against (``kernels.common.compiler_params``).
 """
 
 from __future__ import annotations
@@ -16,11 +18,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import compiler_params
 
-def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
+
+def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, precision):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -28,7 +33,8 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=precision)
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -48,8 +54,12 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *,
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
     n_k = k // bk
     out_dtype = out_dtype or a.dtype
+    # float32 operands contract at full float32 precision (the MXU's
+    # multi-pass mode); bfloat16 products are exact in the default mode
+    precision = (lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else lax.Precision.DEFAULT)
     return pl.pallas_call(
-        functools.partial(_matmul_kernel, n_k=n_k),
+        functools.partial(_matmul_kernel, n_k=n_k, precision=precision),
         grid=(m // bm, n // bn, n_k),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -58,5 +68,6 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(a, b)

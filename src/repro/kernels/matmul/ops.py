@@ -1,7 +1,8 @@
 """jit'd public wrapper for the matmul kernel: pads arbitrary shapes to
-block multiples, resolves block sizes from an explicit :class:`TilePlan`
-(or the VMEM-fitting heuristic when none is given), falls back to the
-oracle for tiny problems where padding would dominate."""
+block multiples and resolves block sizes from an explicit
+:class:`TilePlan` (or the VMEM-fitting heuristic when none is given).
+Blocks are capped at each dimension's 128-padded extent, so small
+problems run as one padded block instead of leaving the kernel."""
 
 from __future__ import annotations
 
@@ -10,11 +11,9 @@ from typing import Optional
 
 import jax
 
-from ..common import TilePlan, VMEM_BUDGET, heuristic_matmul_blocks, pad_axes
+from ..common import (MIN_TILE, TilePlan, heuristic_matmul_blocks, pad_axes,
+                      resolve_interpret, round_up)
 from .matmul import matmul_pallas
-from .ref import matmul_ref
-
-_VMEM_BUDGET = VMEM_BUDGET  # historical name, kept for callers/tests
 
 
 def _pick_blocks(m: int, n: int, k: int, bytes_per_el: int,
@@ -28,28 +27,30 @@ def _pick_blocks(m: int, n: int, k: int, bytes_per_el: int,
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "out_dtype", "tiles"))
-def matmul(a: jax.Array, b: jax.Array, *, interpret: bool = True,
+def matmul(a: jax.Array, b: jax.Array, *, interpret: Optional[bool] = None,
            out_dtype=None, tiles: Optional[TilePlan] = None) -> jax.Array:
     """C = A @ B for any (M, K) x (K, N).
 
-    ``interpret=True`` (the default here) runs the kernel body in the Pallas
-    interpreter — the CPU-validation mode; on TPU pass interpret=False.
-    ``tiles`` is a matmul :class:`TilePlan` (dims bm/bn/bk); omitted, the
-    historical heuristic blocks are used.
+    ``interpret`` defaults to the platform: the Pallas interpreter off the
+    TPU, the compiled kernel on it.  ``tiles`` is a matmul
+    :class:`TilePlan` (dims bm/bn/bk); omitted, the historical heuristic
+    blocks are used.
     """
     m, k = a.shape
     _, n = b.shape
     out_dtype = out_dtype or a.dtype
-    if min(m, n, k) < 128:
-        return matmul_ref(a, b, out_dtype=out_dtype)
     if tiles is not None:
         if tiles.kernel != "matmul":
             raise ValueError(f"TilePlan for {tiles.kernel!r} passed to matmul")
         bm, bn, bk = tiles["bm"], tiles["bn"], tiles["bk"]
     else:
         bm, bn, bk = _pick_blocks(m, n, k, a.dtype.itemsize)
+    bm = min(bm, round_up(m, MIN_TILE))
+    bn = min(bn, round_up(n, MIN_TILE))
+    bk = min(bk, round_up(k, MIN_TILE))
     ap = pad_axes(a, {0: bm, 1: bk})
     bp = pad_axes(b, {0: bk, 1: bn})
-    out = matmul_pallas(ap, bp, bm=bm, bn=bn, bk=bk, interpret=interpret,
+    out = matmul_pallas(ap, bp, bm=bm, bn=bn, bk=bk,
+                        interpret=resolve_interpret(interpret),
                         out_dtype=out_dtype)
     return out[:m, :n]

@@ -8,14 +8,14 @@ from typing import Optional
 
 import jax
 
-from ..common import TilePlan, pad_axes, tile_block
+from ..common import TilePlan, pad_axes, resolve_interpret, tile_block
 from .ref import ssm_scan_ref
 from .ssm_scan import ssm_scan_pallas
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tiles"))
 def ssm_scan(q: jax.Array, k: jax.Array, v: jax.Array, log_a: jax.Array, *,
-             interpret: bool = True,
+             interpret: Optional[bool] = None,
              tiles: Optional[TilePlan] = None) -> jax.Array:
     """q, k: (B, H, S, DK); v: (B, H, S, DV); log_a: (B, H, S).
 
@@ -37,5 +37,6 @@ def ssm_scan(q: jax.Array, k: jax.Array, v: jax.Array, log_a: jax.Array, *,
     sp = qp.shape[2]
     y = ssm_scan_pallas(qp.reshape(b * h, sp, dk), kp.reshape(b * h, sp, dk),
                         vp.reshape(b * h, sp, dv),
-                        lap.reshape(b * h, sp), bs=bs, interpret=interpret)
+                        lap.reshape(b * h, sp), bs=bs,
+                        interpret=resolve_interpret(interpret))
     return y.reshape(b, h, sp, dv)[:, :, :s, :]

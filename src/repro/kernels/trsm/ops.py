@@ -1,5 +1,7 @@
 """Blocked triangular solve built from the diagonal-block kernel + the MXU
-matmul kernel: all O(n^3) off-diagonal work is dgemm-shaped."""
+matmul kernel: all O(n^3) off-diagonal work is dgemm-shaped.  Operands
+are padded (U identity-extended, B zero-extended) to the block, so every
+shape runs through the kernels."""
 
 from __future__ import annotations
 
@@ -9,9 +11,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..common import TilePlan, tile_block
+from ..common import (MIN_TILE, TilePlan, pad_axes, pad_eye,
+                      resolve_interpret, round_up, tile_block)
 from ..matmul.ops import matmul
-from .ref import trsm_ref
 from .trsm import trsm_diag_pallas
 
 
@@ -19,18 +21,23 @@ from .trsm import trsm_diag_pallas
                    static_argnames=("interpret", "block", "tiles",
                                     "mm_tiles"))
 def trsm(u: jax.Array, b: jax.Array, *, block: int = 256,
-         interpret: bool = True, tiles: Optional[TilePlan] = None,
+         interpret: Optional[bool] = None, tiles: Optional[TilePlan] = None,
          mm_tiles: Optional[TilePlan] = None) -> jax.Array:
     """Solve X U = B; U (n, n) upper-triangular, B (m, n).
 
     ``tiles`` (a trsm :class:`TilePlan`, dim ``block``) overrides the block
     size; ``mm_tiles`` is threaded to the trailing-update dgemms.
+    ``interpret`` defaults to the platform (see ``resolve_interpret``).
     """
+    interpret = resolve_interpret(interpret)
     block = tile_block(tiles, "trsm", "block", block)
+    n0 = u.shape[0]
+    m0 = b.shape[0]
+    block = min(block, round_up(n0, MIN_TILE))
+    u = pad_eye(u, round_up(n0, block))
+    b = pad_axes(b, {0: MIN_TILE, 1: block})
     n = u.shape[0]
     m = b.shape[0]
-    if n % block != 0 or m % 128 != 0 or n < block:
-        return trsm_ref(u, b)
     nb = n // block
     x_blocks = []
     b_cur = b
@@ -49,4 +56,4 @@ def trsm(u: jax.Array, b: jax.Array, *, block: int = 256,
             tail = jax.lax.slice(b_cur, (0, (j + 1) * block), (m, n)) - upd
             b_cur = jnp.concatenate(
                 [jax.lax.slice(b_cur, (0, 0), (m, (j + 1) * block)), tail], axis=1)
-    return jnp.concatenate(x_blocks, axis=1)
+    return jnp.concatenate(x_blocks, axis=1)[:m0, :n0]

@@ -8,6 +8,12 @@ off-diagonal work runs through the MXU matmul kernel.  This mirrors how
 LibSci's dtrsm spends its flops in dgemm-shaped updates (paper Fig. 1 shows
 dtrsm below dgemm efficiency for the same reason).
 
+The column loop is right-looking and touches no dynamic slice: column k
+of the working block and row k of U are picked out with iota masks and
+exact masked reductions, the rank-1 update runs on whole VMEM tiles, and
+the solved column overwrites column k of the f32 working block, which
+then holds X.  Everything is computed in float32 on the VPU.
+
 Grid: (M/bm,) row blocks of B, each solved independently against U.
 """
 
@@ -21,27 +27,33 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import compiler_params
 
-def _trsm_diag_kernel(u_ref, b_ref, x_ref, acc_ref, *, nb: int):
+
+def _trsm_diag_kernel(u_ref, b_ref, x_ref, w_ref, *, nb: int):
     """Back-substitution of one (bm, nb) block of B against (nb, nb) U."""
-    u = u_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    w_ref[...] = b_ref[...].astype(jnp.float32)
+    bm = w_ref.shape[0]
 
-    def body(k, _):
-        # s_i = sum_{j<k} x_ij * u_jk  — column k of U is zero below the
-        # diagonal and x[:, k:] is still zero, so a full matvec is exact.
-        ucol = lax.dynamic_slice(u, (0, k), (nb, 1))            # (nb, 1)
-        s = jnp.dot(acc_ref[...], ucol,
-                    preferred_element_type=jnp.float32)         # (bm, 1)
-        bcol = lax.dynamic_slice(b, (0, k), (b.shape[0], 1))
-        ukk = lax.dynamic_slice(u, (k, k), (1, 1))
-        xcol = (bcol - s) / ukk
-        acc_ref[:, pl.ds(k, 1)] = xcol
-        return 0
+    def body(k, carry):
+        u = u_ref[...].astype(jnp.float32)
+        u_row = lax.broadcasted_iota(jnp.int32, (nb, nb), 0)
+        lane = lax.broadcasted_iota(jnp.int32, (1, nb), 1)
+        col = lax.broadcasted_iota(jnp.int32, (bm, nb), 1)
+        urow = jnp.sum(jnp.where(u_row == k, u, 0.0), axis=0,
+                       keepdims=True)                           # U[k, :]
+        ukk = jnp.sum(jnp.where(lane == k, urow, 0.0), axis=1,
+                      keepdims=True)                            # (1, 1)
+        w = w_ref[...]
+        xk = jnp.sum(jnp.where(col == k, w, 0.0), axis=1,
+                     keepdims=True) / ukk                       # (bm, 1)
+        # B[:, j] -= x_k u_kj for j > k; column k becomes X[:, k]
+        upd = w - xk * jnp.where(lane > k, urow, 0.0)
+        w_ref[...] = jnp.where(col == k, xk, upd)
+        return carry
 
     lax.fori_loop(0, nb, body, 0)
-    x_ref[...] = acc_ref[...].astype(x_ref.dtype)
+    x_ref[...] = w_ref[...].astype(x_ref.dtype)
 
 
 def trsm_diag_pallas(u: jax.Array, b: jax.Array, *, bm: int = 256,
@@ -64,5 +76,6 @@ def trsm_diag_pallas(u: jax.Array, b: jax.Array, *, bm: int = 256,
         out_specs=pl.BlockSpec((bm, nb), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
         scratch_shapes=[pltpu.VMEM((bm, nb), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(u, b)

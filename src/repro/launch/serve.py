@@ -17,6 +17,9 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
 
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import jax
     import jax.numpy as jnp
     import numpy as np

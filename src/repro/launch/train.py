@@ -24,6 +24,9 @@ def main():
                     help="pods,data,model (elastic override)")
     args = ap.parse_args()
 
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if os.environ.get("JAX_COORDINATOR_ADDRESS"):
         import jax
         jax.distributed.initialize()

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from .grid import grid_size, n_layers
 
 MatMul = Callable[[jax.Array, jax.Array], jax.Array]
@@ -101,7 +100,8 @@ def _cannon_body(a, b, *, g: int, steps: int, layers: int, s: int,
     return c
 
 
-def _make(mesh, *, overlap: bool, local_mm: Optional[MatMul] = None):
+def _make(mesh, *, overlap: bool, local_mm: Optional[MatMul] = None,
+          check_vma: bool = True):
     g = grid_size(mesh)
     c_layers = n_layers(mesh)
     if c_layers > 1 and g % c_layers != 0:
@@ -112,14 +112,18 @@ def _make(mesh, *, overlap: bool, local_mm: Optional[MatMul] = None):
 
     fn = functools.partial(_cannon_body, g=g, steps=s, layers=c_layers, s=s,
                            local_mm=mm, overlap=overlap)
-    return jax.jit(compat.shard_map(
-        fn, mesh=mesh, in_specs=(in_spec, in_spec), out_specs=in_spec))
+    return jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(in_spec, in_spec), out_specs=in_spec,
+        check_vma=check_vma))
 
 
-def make(mesh, variant: str, *, local_mm: Optional[MatMul] = None):
+def make(mesh, variant: str, *, local_mm: Optional[MatMul] = None,
+         check_vma: bool = True):
     """Reusable compiled executor: (A, B) -> C for the given variant (the
-    2d/2.5d split is carried by the mesh's layer axis)."""
-    return _make(mesh, overlap=variant.endswith("ovlp"), local_mm=local_mm)
+    2d/2.5d split is carried by the mesh's layer axis).  ``check_vma=False``
+    for locals the varying-axis checker cannot type (Pallas kernels)."""
+    return _make(mesh, overlap=variant.endswith("ovlp"), local_mm=local_mm,
+                 check_vma=check_vma)
 
 
 def cannon_2d(A, B, *, mesh, local_mm: Optional[MatMul] = None):

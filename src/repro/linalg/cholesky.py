@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from .grid import grid_size, n_layers
 
 MatMul = Callable[[jax.Array, jax.Array], jax.Array]
@@ -116,7 +115,7 @@ def _chol_body(a, *, g: int, layers: int, local_mm: MatMul, local_chol: Chol,
     if layers > 1:
         # the body's layer-striped masks make the carry vary over 'lyr'
         carry0 = jax.tree.map(
-            lambda x: compat.pcast_varying(x, ("lyr",)), carry0)
+            lambda x: lax.pcast(x, ("lyr",), to="varying"), carry0)
     (a, acc, l_acc), _ = lax.scan(step, carry0, jnp.arange(g))
     if layers > 1:
         # All layers computed identical panels; select layer 0's copy via a
@@ -133,7 +132,7 @@ def _chol_body(a, *, g: int, layers: int, local_mm: MatMul, local_chol: Chol,
 
 def _make(mesh, *, overlap: bool, local_mm: Optional[MatMul] = None,
           local_chol: Optional[Chol] = None,
-          local_solve: Optional[PanelSolve] = None):
+          local_solve: Optional[PanelSolve] = None, check_vma: bool = True):
     g = grid_size(mesh)
     layers = n_layers(mesh)
     fn = functools.partial(_chol_body, g=g, layers=layers,
@@ -142,17 +141,19 @@ def _make(mesh, *, overlap: bool, local_mm: Optional[MatMul] = None,
                            local_solve=local_solve or _default_panel_solve,
                            overlap=overlap)
     spec = P("row", "col")  # replicated over lyr when present
-    return jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=(spec,),
-                                    out_specs=spec))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(spec,),
+                                 out_specs=spec, check_vma=check_vma))
 
 
 def make(mesh, variant: str, *, local_mm: Optional[MatMul] = None,
          local_chol: Optional[Chol] = None,
-         local_solve: Optional[PanelSolve] = None):
+         local_solve: Optional[PanelSolve] = None, check_vma: bool = True):
     """Reusable compiled executor: A -> L for the given variant (the
-    2d/2.5d split is carried by the mesh's layer axis)."""
+    2d/2.5d split is carried by the mesh's layer axis).  ``check_vma=False``
+    for locals the varying-axis checker cannot type (Pallas kernels)."""
     return _make(mesh, overlap=variant.endswith("ovlp"), local_mm=local_mm,
-                 local_chol=local_chol, local_solve=local_solve)
+                 local_chol=local_chol, local_solve=local_solve,
+                 check_vma=check_vma)
 
 
 def cholesky_2d(A, *, mesh, local_mm: Optional[MatMul] = None,

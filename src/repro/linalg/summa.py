@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from .grid import grid_size, n_layers
 
 MatMul = Callable[[jax.Array, jax.Array], jax.Array]
@@ -77,7 +76,8 @@ def _summa_body(a, b, *, steps: int, layers: int, s: int,
     return c
 
 
-def _make(mesh, *, overlap: bool, local_mm: Optional[MatMul] = None):
+def _make(mesh, *, overlap: bool, local_mm: Optional[MatMul] = None,
+          check_vma: bool = True):
     g = grid_size(mesh)
     layers = n_layers(mesh)
     if layers > 1 and g % layers != 0:
@@ -86,14 +86,17 @@ def _make(mesh, *, overlap: bool, local_mm: Optional[MatMul] = None):
     fn = functools.partial(_summa_body, steps=s, layers=layers, s=s,
                            local_mm=local_mm or _default_mm, overlap=overlap)
     spec = P("row", "col")
-    return jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=(spec, spec),
-                                    out_specs=spec))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec),
+                                 out_specs=spec, check_vma=check_vma))
 
 
-def make(mesh, variant: str, *, local_mm: Optional[MatMul] = None):
+def make(mesh, variant: str, *, local_mm: Optional[MatMul] = None,
+         check_vma: bool = True):
     """Reusable compiled executor: (A, B) -> C for the given variant (the
-    2d/2.5d split is carried by the mesh's layer axis)."""
-    return _make(mesh, overlap=variant.endswith("ovlp"), local_mm=local_mm)
+    2d/2.5d split is carried by the mesh's layer axis).  ``check_vma=False``
+    for locals the varying-axis checker cannot type (Pallas kernels)."""
+    return _make(mesh, overlap=variant.endswith("ovlp"), local_mm=local_mm,
+                 check_vma=check_vma)
 
 
 def summa_2d(A, B, *, mesh, local_mm: Optional[MatMul] = None):

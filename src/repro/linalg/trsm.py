@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from .grid import grid_size, n_layers
 
 MatMul = Callable[[jax.Array, jax.Array], jax.Array]
@@ -105,7 +104,7 @@ def _trsm_body(u, b, *, g: int, local_mm: MatMul, local_solve: SolveXU,
 
 
 def _make_2d(mesh, *, overlap: bool, local_mm: Optional[MatMul] = None,
-             local_solve: Optional[SolveXU] = None):
+             local_solve: Optional[SolveXU] = None, check_vma: bool = True):
     g = grid_size(mesh)
     layers = n_layers(mesh)
     fn = functools.partial(_trsm_body, g=g, local_mm=local_mm or _default_mm,
@@ -118,16 +117,18 @@ def _make_2d(mesh, *, overlap: bool, local_mm: Optional[MatMul] = None,
     else:
         u_spec = P("row", "col")
         bx_spec = P("row", "col")
-    return jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=(u_spec, bx_spec),
-                                    out_specs=bx_spec))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(u_spec, bx_spec),
+                                 out_specs=bx_spec, check_vma=check_vma))
 
 
 def make(mesh, variant: str, *, local_mm: Optional[MatMul] = None,
-         local_solve: Optional[SolveXU] = None):
+         local_solve: Optional[SolveXU] = None, check_vma: bool = True):
     """Reusable compiled executor: (U, B) -> X for the given variant (the
-    2d/2.5d split is carried by the mesh's layer axis)."""
+    2d/2.5d split is carried by the mesh's layer axis).  ``check_vma=False``
+    for locals the varying-axis checker cannot type (Pallas kernels)."""
     return _make_2d(mesh, overlap=variant.endswith("ovlp"),
-                    local_mm=local_mm, local_solve=local_solve)
+                    local_mm=local_mm, local_solve=local_solve,
+                    check_vma=check_vma)
 
 
 def trsm_2d(U, B, *, mesh, local_mm: Optional[MatMul] = None,

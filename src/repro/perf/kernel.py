@@ -65,10 +65,12 @@ MIN_TILE = 128
 #: candidate block sizes per dimension (powers of two from the lane tile).
 CANDIDATE_SIZES = (128, 256, 512, 1024)
 
-#: default VMEM budget for the heuristic path — headroom out of ~128 MB,
-#: shared with ``kernels.common`` (defined here so the model layer stays
-#: importable without jax).
-VMEM_BUDGET = 96 * 1024 * 1024
+#: on-chip memory one kernel may use: the TPU compiler's scoped-VMEM
+#: default (16 MiB on v5e).  The linalg kernels compile with exactly this
+#: limit (``kernels.common.compiler_params``) and the machine profiles'
+#: ``vmem_bytes`` gate tiles against it (defined here so the model layer
+#: stays importable without jax).
+VMEM_BUDGET = 16 * 1024 * 1024
 
 _ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4, "float64": 8}
 
@@ -187,8 +189,14 @@ def _matmul_work(shape, tiles, itemsize):
         bytes_out=gm * gn * bm * bn * itemsize,
         steps=steps,
         launches=np.ones_like(steps),
-        vmem_bytes=((bm * bk + bk * bn + bm * bn) * itemsize
-                    + bm * bn * 4.0),
+        # operand and result blocks are double-buffered by the pipeline;
+        # the f32 accumulator and the f32 dot result are single tiles; f32
+        # operands also hold their split halves for the multi-pass MXU
+        # contraction (~8 bytes per operand element, as the v5e compiler
+        # allocates them)
+        vmem_bytes=(2.0 * (bm * bk + bk * bn + bm * bn) * itemsize
+                    + 2.0 * bm * bn * 4.0
+                    + (bm * bk + bk * bn) * (8.0 if itemsize >= 4 else 0.0)),
     )
 
 
@@ -227,7 +235,9 @@ def _trsm_work(shape, tiles, itemsize, mm_tile=None):
         bytes_out=bytes_out,
         steps=diag_steps + mm_steps,
         launches=2.0 * nb - 1.0,          # nb diag solves + nb-1 dgemms
-        vmem_bytes=(b * b + 256.0 * b) * itemsize + 256.0 * b * 4.0,
+        # U, B and X blocks double-buffered; f32 working block + f32 U
+        vmem_bytes=(2.0 * (b * b + 2.0 * 256.0 * b) * itemsize
+                    + (256.0 * b + b * b) * 4.0),
     )
 
 
@@ -260,7 +270,8 @@ def _cholesky_work(shape, tiles, itemsize, mm_tile=None):
         bytes_out=bytes_out,
         steps=diag_steps + mm_steps,
         launches=3.0 * nb - 2.0,
-        vmem_bytes=(b * b * 2.0) * itemsize + b * b * 4.0,
+        # A and L blocks double-buffered; f32 working block + transpose
+        vmem_bytes=2.0 * (b * b * 2.0) * itemsize + 2.0 * b * b * 4.0,
     )
 
 

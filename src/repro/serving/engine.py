@@ -82,16 +82,11 @@ class Engine:
         if not telemetry.enabled():
             return None
         from ..tuner.plan import machine_fingerprint
-        from ..tuner.registry import DEFAULT_REGISTRY, machine_for_platform
+        from ..tuner.registry import DEFAULT_REGISTRY, machine_for_devices
         devs = jax.devices()
-        platform = devs[0].platform
-        name = machine_for_platform(platform)
-        try:
-            profile = DEFAULT_REGISTRY.machine(name).machine
-        except KeyError:
-            profile = name
-        fp = machine_fingerprint(profile, platform,
-                                 getattr(devs[0], "device_kind", platform),
+        name = machine_for_devices(devs)
+        fp = machine_fingerprint(DEFAULT_REGISTRY.machine(name).machine,
+                                 devs[0].platform, devs[0].device_kind,
                                  len(devs))
         arch = getattr(getattr(self.model, "cfg", None), "name",
                        type(self.model).__name__)
@@ -101,7 +96,7 @@ class Engine:
             meta={"max_new_tokens": self.cfg.max_new_tokens})
 
     def _make_scheduler(self, batch: int, phase_timer):
-        from ..core.machine import CPU_HOST
+        from ..tuner.registry import DEFAULT_REGISTRY, machine_for_devices
         from .cost import cost_model_for
         from .policy import FIFOPolicy
         from .scheduler import ModelBackend, Scheduler, SchedulerConfig
@@ -111,7 +106,8 @@ class Engine:
                 self.model, self.params,
                 max_cache_len=self.cfg.max_cache_len,
                 prefill_chunk=self.cfg.prefill_chunk, step=self._step)
-        cost = cost_model_for(self.model.cfg, CPU_HOST)
+        machine = DEFAULT_REGISTRY.machine(machine_for_devices()).machine
+        cost = cost_model_for(self.model.cfg, machine)
         scfg = SchedulerConfig(max_cache_len=self.cfg.max_cache_len,
                                max_batch=max(batch, 1),
                                max_active=max(batch, 1))

@@ -840,12 +840,14 @@ def build_scheduler(model=None, params=None, *, cfg_model=None,
     (:class:`ModelBackend`); without: cost-model simulation
     (:class:`SimBackend`).  ``cfg_model`` is the ModelConfig the cost
     model describes (defaults to ``model.cfg``)."""
-    from ..core.machine import CPU_HOST
+    from ..tuner.registry import DEFAULT_REGISTRY, machine_for_devices
 
     mcfg = cfg_model if cfg_model is not None else getattr(model, "cfg", None)
     if mcfg is None:
         raise ValueError("need cfg_model (or a model with .cfg)")
-    cost = cost_model_for(mcfg, machine or CPU_HOST)
+    if machine is None:
+        machine = DEFAULT_REGISTRY.machine(machine_for_devices()).machine
+    cost = cost_model_for(mcfg, machine)
     scfg = (scheduler_cfg or SchedulerConfig()).resolve()
     if backend is None:
         if model is not None:

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 
 
 def _quantize(x, *, dtype=jnp.int8):
@@ -56,9 +55,9 @@ def compress_pod_mean(grads: Any, err: Any, mesh) -> Tuple[Any, Any]:
             return jnp.mean(deq, axis=0).astype(gl.dtype), new_e
 
         spec = P()  # replicated over pod inside each pod's shards
-        return compat.shard_map(body, mesh=mesh,
-                                in_specs=(spec, spec), out_specs=(spec, spec),
-                                check_vma=False)(g, e)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(spec, spec), out_specs=(spec, spec),
+                             check_vma=False)(g, e)
 
     flat_g, treedef = jax.tree.flatten(grads)
     flat_e = jax.tree.leaves(err)

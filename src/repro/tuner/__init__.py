@@ -16,14 +16,15 @@ Layout:
 """
 
 from .registry import (DEFAULT_REGISTRY, MachineSurface, PerfModelRegistry,
-                       build_default_registry, machine_for_platform)
+                       build_default_registry, machine_for_devices,
+                       machine_for_platform)
 from .plan import (ExecutionPlan, PlanCache, default_plan_dir,
                    machine_fingerprint, plan_key)
 from .autotune import OP_ALGOS, Tuner, default_tuner, feasible_grids
 
 __all__ = [
     "DEFAULT_REGISTRY", "MachineSurface", "PerfModelRegistry",
-    "build_default_registry", "machine_for_platform",
+    "build_default_registry", "machine_for_devices", "machine_for_platform",
     "ExecutionPlan", "PlanCache", "default_plan_dir", "machine_fingerprint",
     "plan_key",
     "OP_ALGOS", "Tuner", "default_tuner", "feasible_grids",

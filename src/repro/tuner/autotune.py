@@ -143,7 +143,7 @@ class Tuner:
                                                  platform)
         platform = platform or "cpu"
         device_kind = device_kind or platform
-        machine = machine or machine_for_platform(platform)
+        machine = machine or machine_for_platform(platform, device_kind)
         # A degraded surface (diagnosis attached a FaultSpec) demands the
         # simulator: only the per-rank engine sees link granularity, so
         # closed-form-only planning would ignore the fault entirely.

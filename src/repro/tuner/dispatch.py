@@ -9,7 +9,7 @@ chosen ``shard_map`` variant with the planned local kernels:
 
 * ``local_kernel="pallas"`` wires the Pallas kernels
   (``kernels.matmul/trsm/cholesky``) in as the local matmul / triangular
-  solve / diagonal factor (interpret-mode off TPU);
+  solve / diagonal factor (compiled on the TPU, interpreted elsewhere);
 * ``local_kernel="jnp"`` (the CPU default) uses the ``jnp.dot`` /
   ``jax.scipy`` locals.
 
@@ -37,7 +37,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.cholesky.ops import cholesky as _kchol
-from ..kernels.common import TilePlan
+from ..kernels.common import TilePlan, pad_eye
 from ..kernels.matmul.ops import matmul as _kmm
 from ..kernels.trsm.ops import trsm as _ktrsm
 # NB: import the factories, not the modules — the linalg package shadows
@@ -131,7 +131,11 @@ def _executor(plan: ExecutionPlan, mesh, devices: Tuple, interpret: bool):
     if fn is None:
         hooks = _local_hooks(plan.algo, plan.local_kernel, interpret,
                              plan.tiles)
-        fn = _MAKERS[plan.algo](mesh, plan.variant, **hooks)
+        # the varying-axis checker cannot type a Pallas kernel's body (the
+        # interpreter evaluates it inside the shard_map), so executors with
+        # Pallas locals are built unchecked
+        fn = _MAKERS[plan.algo](mesh, plan.variant, check_vma=not hooks,
+                                **hooks)
         with _LOCK:
             if len(_EXECUTORS) > 64:
                 _EXECUTORS.clear()
@@ -147,16 +151,6 @@ def _round_up(x: int, m: int) -> int:
 
 def _pad_zero(x, rows: int, cols: int):
     return jnp.pad(x, ((0, rows - x.shape[0]), (0, cols - x.shape[1])))
-
-
-def _pad_eye(x, size: int):
-    """blockdiag(x, I): structure-preserving pad for triangular/SPD args."""
-    n = x.shape[0]
-    if size == n:
-        return x
-    out = _pad_zero(x, size, size)
-    idx = jnp.arange(n, size)
-    return out.at[idx, idx].set(jnp.ones((), x.dtype))
 
 
 def _check_square(name: str, x) -> int:
@@ -181,6 +175,17 @@ def _resolve(devices: Optional[Sequence], plan_p: int) -> Tuple:
     return tuple(devices[:plan_p])
 
 
+def executor(plan: ExecutionPlan, devices: Optional[Sequence] = None):
+    """The plan's memoized jitted executor and the mesh it runs on — what
+    :func:`execute` calls on the distributed operands (lower it to inspect
+    or pre-compile the program).  Pallas locals are compiled on the TPU
+    and interpreted elsewhere."""
+    devs = _resolve(devices, plan.p)
+    interpret = devs[0].platform != "tpu"
+    mesh = _mesh_for(plan.g, plan.c, devs)
+    return _executor(plan, mesh, devs, interpret), mesh
+
+
 def execute(plan: ExecutionPlan, *operands,
             devices: Optional[Sequence] = None, observe: bool = False,
             store=None, _plan_seconds: float = 0.0):
@@ -193,10 +198,7 @@ def execute(plan: ExecutionPlan, *operands,
     model-guided wrappers account the planning time they already spent."""
     from .. import telemetry
     from ..telemetry import phase_scope as _phase
-    devs = _resolve(devices, plan.p)
-    interpret = devs[0].platform != "tpu"
-    mesh = _mesh_for(plan.g, plan.c, devs)
-    fn = _executor(plan, mesh, devs, interpret)
+    fn, mesh = executor(plan, devices)
     pt = None
     if observe or telemetry.enabled() or obs.enabled():
         pt = telemetry.timer_for_plan(plan, kind="dispatch")
@@ -226,7 +228,7 @@ def execute(plan: ExecutionPlan, *operands,
             mb = _round_up(n, c * g)
             bx_spec = P(("lyr", "row"), "col") if c > 1 else P("row", "col")
             with _phase(pt, "distribute"):
-                ud = distribute(_pad_eye(u, m), mesh, P("row", "col"))
+                ud = distribute(pad_eye(u, m), mesh, P("row", "col"))
                 bd = distribute(_pad_zero(b, mb, m), mesh, bx_spec)
             with _phase(pt, "execute"):
                 out = fn(ud, bd)[:n, :n]
@@ -236,7 +238,7 @@ def execute(plan: ExecutionPlan, *operands,
             (a,) = (jnp.asarray(x) for x in operands)
             m = _round_up(n, g)
             with _phase(pt, "distribute"):
-                ad = distribute(_pad_eye(a, m), mesh, P("row", "col"))
+                ad = distribute(pad_eye(a, m), mesh, P("row", "col"))
             with _phase(pt, "execute"):
                 out = fn(ad)[:n, :n]
                 if pt is not None:

@@ -202,13 +202,32 @@ def build_default_registry() -> PerfModelRegistry:
 DEFAULT_REGISTRY = build_default_registry()
 
 
-#: machine chosen per JAX backend platform when the caller does not name one
-PLATFORM_MACHINES = {
-    "cpu": CPU_HOST.name,
-    "tpu": TPU_V5E.name,
+#: machine profile per JAX ``device_kind``; the CPU backend is the host
+DEVICE_KIND_MACHINES = {
+    "TPU v5 lite": TPU_V5E.name,
 }
 
 
-def machine_for_platform(platform: str) -> str:
-    """Best-match registered machine for a jax device platform string."""
-    return PLATFORM_MACHINES.get(platform, CPU_HOST.name)
+def machine_for_platform(platform: str,
+                         device_kind: Optional[str] = None) -> str:
+    """The registered machine profile for a jax device: ``cpu-host`` on
+    the CPU backend, else the profile of its ``device_kind``.  A device
+    without a profile is an error, never a default."""
+    if platform == "cpu":
+        return CPU_HOST.name
+    try:
+        return DEVICE_KIND_MACHINES[device_kind]
+    except KeyError:
+        raise ValueError(f"no machine profile for {platform} device kind "
+                         f"{device_kind!r}; known: "
+                         f"{sorted(DEVICE_KIND_MACHINES)}") from None
+
+
+def machine_for_devices(devices: Optional[Sequence] = None) -> str:
+    """``machine_for_platform`` of the first of ``devices`` (default: the
+    process's jax devices)."""
+    if devices is None:
+        import jax
+        devices = jax.devices()
+    dev = list(devices)[0]
+    return machine_for_platform(dev.platform, dev.device_kind)

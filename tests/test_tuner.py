@@ -52,8 +52,12 @@ class TestRegistry:
 
     def test_machine_for_platform(self):
         assert machine_for_platform("cpu") == CPU_HOST.name
-        assert machine_for_platform("tpu") == "tpu-v5e"
-        assert machine_for_platform("rocm") == CPU_HOST.name
+        assert machine_for_platform("tpu", "TPU v5 lite") == "tpu-v5e"
+        # a device without a profile is an error, never a default
+        with pytest.raises(ValueError, match="no machine profile"):
+            machine_for_platform("tpu", "TPU v4")
+        with pytest.raises(ValueError, match="no machine profile"):
+            machine_for_platform("rocm")
 
 
 class TestLegalCValues:
