@@ -19,8 +19,6 @@ import hashlib
 import json
 from typing import Optional
 
-from ..perf.kernel import VMEM_BUDGET
-
 
 @dataclasses.dataclass(frozen=True)
 class KernelConstants:
@@ -44,7 +42,8 @@ class KernelConstants:
     c_d2h: float             # s fixed output-side setup per kernel launch
     overhead_factor: float   # >= 1 multiplier on pure issue/execute time
     loop_overhead: float     # s per grid step (index math, task switch)
-    vmem_bytes: float        # usable on-chip bytes for one step's blocks
+    vmem_bytes: float        # on-chip bytes one kernel may use: the
+                             # compiler's scoped-VMEM limit on this chip
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,7 +145,10 @@ TPU_V5E = Machine(
         bw_h2d=819e9, bw_d2h=410e9,
         c_h2d=2e-6, c_d2h=5e-6,
         overhead_factor=1.35, loop_overhead=1.5e-6,
-        vmem_bytes=VMEM_BUDGET),
+        # the TPU compiler's scoped-VMEM default on v5e, which the Pallas
+        # kernels compile under (tests/test_tpu_compile.py compiles the
+        # largest tiles this admits)
+        vmem_bytes=16 * 1024 * 1024),
 )
 
 # ---------------------------------------------------------------------------
@@ -177,7 +179,9 @@ CPU_HOST = Machine(
         bw_h2d=8e9, bw_d2h=4e9,
         c_h2d=2e-4, c_d2h=2e-4,
         overhead_factor=2.0, loop_overhead=5e-4,
-        vmem_bytes=VMEM_BUDGET),
+        # the interpreter runs the TPU kernels unchanged: plan them under
+        # the limit of the chip the CPU runs rehearse
+        vmem_bytes=TPU_V5E.kernel_constants.vmem_bytes),
 )
 
 MACHINES = {m.name: m for m in (HOPPER, TPU_V5E, CPU_HOST)}

@@ -26,8 +26,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import compiler_params
-
 
 def _chol_kernel(a_ref, l_ref, w_ref, *, nb: int):
     a = a_ref[...].astype(jnp.float32)
@@ -68,6 +66,5 @@ def cholesky_block_pallas(a: jax.Array, *, interpret: bool = False) -> jax.Array
         out_specs=pl.BlockSpec((nb, nb), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, nb), a.dtype),
         scratch_shapes=[pltpu.VMEM((nb, nb), jnp.float32)],
-        compiler_params=compiler_params(),
         interpret=interpret,
     )(a)

@@ -16,16 +16,15 @@ from typing import Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 # the model layer is pure numpy — importing it pulls no jax machinery in
 from ..perf.kernel import (MIN_TILE, TilePlan, VMEM_BUDGET,
                            heuristic_matmul_blocks, heuristic_plan)
 
 __all__ = [
-    "MIN_TILE", "TilePlan", "VMEM_BUDGET", "compiler_params",
-    "heuristic_matmul_blocks", "heuristic_plan", "pad_axes",
-    "pad_eye", "resolve_interpret", "round_up", "tile_block",
+    "MIN_TILE", "TilePlan", "VMEM_BUDGET", "heuristic_matmul_blocks",
+    "heuristic_plan", "pad_axes", "pad_eye", "resolve_interpret",
+    "round_up", "tile_block",
 ]
 
 
@@ -35,12 +34,6 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return bool(interpret)
-
-
-def compiler_params() -> pltpu.CompilerParams:
-    """Mosaic parameters of the linalg kernels: the scoped-VMEM limit is
-    the same ``VMEM_BUDGET`` the tile model plans against."""
-    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET)
 
 
 def round_up(x: int, m: int) -> int:

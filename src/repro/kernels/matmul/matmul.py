@@ -8,8 +8,8 @@ once, on the last K step (revisiting semantics).
 Block sizes default to (256, 256, 512): A-block 256x512 + B-block 512x256
 bf16 = 0.5 MB and the f32 accumulator 0.25 MB comfortably fit VMEM while
 keeping every matmul dimension a multiple of the 128x128 MXU tile.  The
-kernel is compiled under the scoped-VMEM limit the tile model plans
-against (``kernels.common.compiler_params``).
+kernel compiles under the chip's scoped-VMEM default, which its machine
+profile states as ``vmem_bytes`` for the tile model.
 """
 
 from __future__ import annotations
@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ..common import compiler_params
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, precision):
@@ -68,6 +66,5 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compiler_params(),
         interpret=interpret,
     )(a, b)

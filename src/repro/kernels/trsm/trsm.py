@@ -27,8 +27,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import compiler_params
-
 
 def _trsm_diag_kernel(u_ref, b_ref, x_ref, w_ref, *, nb: int):
     """Back-substitution of one (bm, nb) block of B against (nb, nb) U."""
@@ -76,6 +74,5 @@ def trsm_diag_pallas(u: jax.Array, b: jax.Array, *, bm: int = 256,
         out_specs=pl.BlockSpec((bm, nb), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
         scratch_shapes=[pltpu.VMEM((bm, nb), jnp.float32)],
-        compiler_params=compiler_params(),
         interpret=interpret,
     )(u, b)

@@ -40,6 +40,8 @@ from typing import Dict, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..core.machine import TPU_V5E
+
 #: block-dimension names per kernel family, in wrapper argument order.
 KERNEL_DIMS: Dict[str, Tuple[str, ...]] = {
     "matmul": ("bm", "bn", "bk"),
@@ -65,12 +67,10 @@ MIN_TILE = 128
 #: candidate block sizes per dimension (powers of two from the lane tile).
 CANDIDATE_SIZES = (128, 256, 512, 1024)
 
-#: on-chip memory one kernel may use: the TPU compiler's scoped-VMEM
-#: default (16 MiB on v5e).  The linalg kernels compile with exactly this
-#: limit (``kernels.common.compiler_params``) and the machine profiles'
-#: ``vmem_bytes`` gate tiles against it (defined here so the model layer
-#: stays importable without jax).
-VMEM_BUDGET = 16 * 1024 * 1024
+#: on-chip memory budget of the heuristic block choice: the v5e profile's
+#: ``vmem_bytes`` (the compiler's scoped-VMEM limit there).  The model
+#: gates tiles against the ``vmem_bytes`` of the machine it plans for.
+VMEM_BUDGET = int(TPU_V5E.kernel_constants.vmem_bytes)
 
 _ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4, "float64": 8}
 
@@ -189,14 +189,16 @@ def _matmul_work(shape, tiles, itemsize):
         bytes_out=gm * gn * bm * bn * itemsize,
         steps=steps,
         launches=np.ones_like(steps),
-        # operand and result blocks are double-buffered by the pipeline;
-        # the f32 accumulator and the f32 dot result are single tiles; f32
-        # operands also hold their split halves for the multi-pass MXU
-        # contraction (~8 bytes per operand element, as the v5e compiler
-        # allocates them)
+        # operand and result blocks are double-buffered by the pipeline and
+        # the f32 accumulator is one tile; the compiler adds 16 bytes per
+        # A-block element for an f32 multi-pass contraction (2 in bf16)
+        # and 512 bytes per block row.  Fitted to the scoped allocation
+        # the v5e compiler reports for every candidate tile: never below
+        # it, equal at many tiles.
         vmem_bytes=(2.0 * (bm * bk + bk * bn + bm * bn) * itemsize
-                    + 2.0 * bm * bn * 4.0
-                    + (bm * bk + bk * bn) * (8.0 if itemsize >= 4 else 0.0)),
+                    + 4.0 * bm * bn
+                    + (16.0 if itemsize >= 4 else 2.0) * bm * bk
+                    + 512.0 * bm),
     )
 
 

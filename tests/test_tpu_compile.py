@@ -98,6 +98,57 @@ def test_matmul_model_tiles_fit_vmem(one_chip, dtype):
     assert "tpu_custom_call" in text
 
 
+def _largest_admitted(kernel, shape, itemsize):
+    """The candidate tiles the v5e model's VMEM gate admits that no other
+    admitted candidate contains: if these compile, every admitted tile
+    does."""
+    import numpy as np
+
+    from repro.core.machine import TPU_V5E
+    from repro.perf.kernel import KernelModel, candidate_tiles
+
+    cands = candidate_tiles(kernel, shape)
+    ok = KernelModel(TPU_V5E).feasible(kernel, shape, cands, itemsize)
+    tiles = np.stack([cands[d] for d in cands], axis=1)[ok]
+    return [tuple(int(v) for v in t) for t in tiles
+            if not any((u >= t).all() and (u > t).any() for u in tiles)]
+
+
+LARGEST_MATMUL = [(dt, t) for dt in DTYPES
+                  for t in _largest_admitted("matmul", (16384,) * 3,
+                                             jnp.dtype(dt).itemsize)]
+
+
+@pytest.mark.parametrize(
+    "dtype,tile", LARGEST_MATMUL,
+    ids=[f"{jnp.dtype(dt).name}-{'x'.join(map(str, t))}"
+         for dt, t in LARGEST_MATMUL])
+def test_matmul_largest_admitted_tiles_compile(one_chip, dtype, tile):
+    """Every tile at the edge of what the VMEM gate admits compiles under
+    the chip's scoped-VMEM limit."""
+    bm, bn, bk = tile
+    a = _on(one_chip, (2 * bm, 2 * bk), dtype)
+    b = _on(one_chip, (2 * bk, 2 * bn), dtype)
+    text = _compiled_text(
+        lambda a, b: matmul_pallas(a, b, bm=bm, bn=bn, bk=bk), a, b)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel", ["trsm", "cholesky"])
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_diag_largest_admitted_block_compiles(one_chip, kernel, dtype):
+    """The largest diagonal block the VMEM gate admits compiles."""
+    shape = (16384, 16384) if kernel == "trsm" else (16384,)
+    ((nb,),) = _largest_admitted(kernel, shape, jnp.dtype(dtype).itemsize)
+    u = _on(one_chip, (nb, nb), dtype)
+    if kernel == "trsm":
+        text = _compiled_text(trsm_diag_pallas, u, _on(one_chip, (1024, nb),
+                                                        dtype))
+    else:
+        text = _compiled_text(cholesky_block_pallas, u)
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
 def test_trsm_diag_block_256(one_chip, dtype):
     u = _on(one_chip, (256, 256), dtype)
