@@ -65,16 +65,15 @@ def main() -> dict:
 
         dispatch.matmul(a, a, tuner=tuner)       # warm: compile + plan
 
-        # block on both sides: the traced path blocks inside the execute
-        # phase (so the span covers real work), and an unblocked baseline
-        # would make the comparison async-vs-sync instead of off-vs-on
+        # block on both sides, outside the call: spans never block, so
+        # this times whole calls, recording off against recording on
         def call():
             jax.block_until_ready(dispatch.matmul(a, a, tuner=tuner))
 
         telemetry.disable()
-        # warm both modes (first traced call builds the tracer and the
-        # PhaseTimer path), then interleave off/on batches so clock and
-        # scheduler drift hit both sides equally; min-of-batches each
+        # warm both modes (first traced call builds the tracer), then
+        # interleave off/on batches so clock and scheduler drift hit both
+        # sides equally; min-of-batches each
         obs.disable()
         call()
         obs.enable(capacity=16384)
@@ -85,7 +84,7 @@ def main() -> dict:
             base_s = min(base_s, _batch_seconds(call))
             obs.enable()
             traced_s = min(traced_s, _batch_seconds(call))
-        n_spans_per_call = 4                      # plan + root + 2 phases
+        n_spans_per_call = 4                      # entry, plan, 2 phases
         out["dispatch_base_us"] = base_s * 1e6
         out["dispatch_traced_us"] = traced_s * 1e6
         out["enabled_overhead_pct"] = max(0.0, traced_s / base_s - 1.0) * 100

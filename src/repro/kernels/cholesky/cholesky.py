@@ -67,4 +67,5 @@ def cholesky_block_pallas(a: jax.Array, *, interpret: bool = False) -> jax.Array
         out_shape=jax.ShapeDtypeStruct((nb, nb), a.dtype),
         scratch_shapes=[pltpu.VMEM((nb, nb), jnp.float32)],
         interpret=interpret,
+        name="cholesky",  # the operation's name in a profiler trace
     )(a)

@@ -67,4 +67,5 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="matmul",  # the operation's name in a profiler trace
     )(a, b)

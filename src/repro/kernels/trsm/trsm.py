@@ -75,4 +75,5 @@ def trsm_diag_pallas(u: jax.Array, b: jax.Array, *, bm: int = 256,
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
         scratch_shapes=[pltpu.VMEM((bm, nb), jnp.float32)],
         interpret=interpret,
+        name="trsm",  # the operation's name in a profiler trace
     )(u, b)

@@ -2,21 +2,22 @@
 
 One switch (:func:`enable` / env ``REPRO_OBS=1``), one process-global
 :class:`~repro.obs.spans.Tracer` and :class:`MetricsRegistry`, and one
-hot-path guard — :func:`enabled` is a single global read and
-:func:`maybe_span` returns a shared no-op context manager when tracing
-is off, so the instrumented layers (telemetry PhaseTimer, tuner
-dispatch, kernel timers, the serving scheduler) pay nothing measurable
-when nobody is watching.  When tracing is on, every timed region that
-knows its model-predicted duration carries it on the span, and
-:mod:`repro.obs.export` renders measured and predicted timelines
-side-by-side with flow links and signed residuals.
+instrumentation hook, :func:`maybe_span`.  The hook always writes a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (a host span in
+any profiler trace taken of the process, with the span's args as its
+metadata; about a microsecond of host time when no profile session is
+active), and
+when recording is on it also records the span in the tracer.  When
+recording is on, every timed region that knows its model-predicted
+duration carries it on the span, and :mod:`repro.obs.export` renders
+measured and predicted timelines side-by-side with flow links and
+signed residuals.
 """
 
 from __future__ import annotations
 
 import os
 import threading
-from contextlib import nullcontext
 from typing import Optional
 
 from .spans import DEFAULT_CAPACITY, Span, Tracer
@@ -32,7 +33,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram",
     "LATENCY_BUCKETS", "REL_ERR_BUCKETS", "DEFAULT_CAPACITY",
     "enabled", "enable", "disable", "reset", "tracer", "default_registry",
-    "maybe_span", "alert",
+    "maybe_span", "annotation", "alert",
     "export_spans", "sim_trace", "serving_trace", "save_trace",
     "summary", "save_summary", "tier_of", "parse_prometheus_text",
     "watch",
@@ -52,10 +53,9 @@ _ENABLED: Optional[bool] = None     # None -> consult the environment
 _TRACER: Optional[Tracer] = None
 _REGISTRY: Optional[MetricsRegistry] = None
 
-#: shared no-op context manager — ``nullcontext`` is reentrant and
-#: reusable, so one instance serves every disabled ``maybe_span`` call
-#: without an allocation.
-_NULL = nullcontext()
+#: ``jax.profiler.TraceAnnotation``, imported on first use so that
+#: importing ``repro.obs`` does not import JAX.
+_ANNOTATION = None
 
 
 def enabled() -> bool:
@@ -126,14 +126,56 @@ def default_registry() -> MetricsRegistry:
     return reg
 
 
+def annotation(name: str, **args):
+    """The profiler half of :func:`maybe_span` alone: a
+    ``jax.profiler.TraceAnnotation`` named ``repro.<name>``, its args as
+    TraceMe metadata (formatted only while a profile session is active).
+    For a region whose tracer span is timed on another clock, as the
+    scheduler's step root is on the scheduler's."""
+    global _ANNOTATION
+    cls = _ANNOTATION
+    if cls is None:
+        from jax.profiler import TraceAnnotation as cls
+        _ANNOTATION = cls
+    return cls("repro." + name, **args)
+
+
+class _Recorded:
+    """A profiler annotation and a tracer span over one region."""
+
+    __slots__ = ("_ann", "_tr", "_sp", "_span_args")
+
+    def __init__(self, ann, tr: Tracer, span_args: tuple):
+        self._ann, self._tr, self._span_args = ann, tr, span_args
+        self._sp = None
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._sp = self._tr.begin(*self._span_args)
+        return self
+
+    def __exit__(self, et, ev, tb):
+        self._tr.end(self._sp, error=et is not None)
+        self._ann.__exit__(et, ev, tb)
+        return False
+
+    def set_metadata(self, **args) -> None:
+        self._ann.set_metadata(**args)
+        self._sp.args.update(args)
+
+
 def maybe_span(name: str, cat: str = "",
                predicted_s: Optional[float] = None, **args):
-    """``tracer().span(...)`` when recording, a shared no-op context
-    manager when not — the one-line instrumentation hook every layer
-    uses."""
+    """The one instrumentation hook every layer uses: a context manager
+    that writes the region into the profiler trace (:func:`annotation`)
+    and, when recording is on, records ``tracer().span(name, cat,
+    predicted_s, **args)`` too.  Pass only ints, floats and short strings
+    as args.  Either way the entered object takes ``set_metadata(**args)``
+    for args known only at the region's end."""
+    ann = annotation(name, **args)
     if not enabled():
-        return _NULL
-    return tracer().span(name, cat, predicted_s, **args)
+        return ann
+    return _Recorded(ann, tracer(), (name, cat, args or None, predicted_s))
 
 
 def alert(name: str, **args) -> Optional[Span]:

@@ -20,6 +20,15 @@ Execution is pluggable:
   predicted step times instead of executing — the trace-replay harness
   (:mod:`.trace`) schedules tens of thousands of requests this way.
 
+Every step writes host spans into any profiler trace taken of the
+process (``repro.obs.maybe_span``): ``repro.serve.step`` around
+:meth:`Scheduler.step`, and inside it ``repro.serve.admit``,
+``repro.serve.compose``, one ``repro.serve.prefill`` per request
+prefilled (the first carries ``queued_s``, the wall time from
+:meth:`Scheduler.submit` to the start of that prefill), and for the
+batched decode ``repro.serve.decode.stack`` / ``.step`` / ``.unstack``
+and ``repro.serve.sample``.
+
 With telemetry on, every real step emits a ``kind="serve_step"`` record
 carrying measured prefill/decode phases *and* the prediction it was
 scheduled under, so the PR-4 residual/refit/drift loop covers the
@@ -85,6 +94,7 @@ class RequestState:
         self.token_budget = token_budget   # KV slots reserved at admission
         self.prefill_pos = 0
         self.out: List[Any] = []           # generated tokens (ints or 0-d arrays)
+        self.submitted_wall_s: float = float("nan")  # perf_counter at submit
         self.admitted_s: float = float("nan")
         self.first_token_s: Optional[float] = None
         self.finish_s: Optional[float] = None
@@ -242,6 +252,7 @@ class Scheduler:
         budget = min(req.prompt_len + req.max_new_tokens,
                      self.cfg.max_cache_len)
         rs = RequestState(req, budget)
+        rs.submitted_wall_s = time.perf_counter()
         if req.arrival_s <= self.clock:
             self.waiting.append(rs)
         else:
@@ -262,9 +273,12 @@ class Scheduler:
         """Admit, compose, execute, account, evict.  Returns None when
         there is nothing at all left to do."""
         tr = obs.tracer() if obs.enabled() else None
-        return self._step_impl(tr)
+        # profiler span only: the tracer's step root below is timed on the
+        # scheduler's clock, which replay simulates
+        with obs.annotation("serve.step", step=self.steps) as ann:
+            return self._step_impl(tr, ann)
 
-    def _step_impl(self, tr) -> Optional[StepReport]:
+    def _step_impl(self, tr, ann) -> Optional[StepReport]:
         self._drain_arrivals()
         self._enforce_deadlines()
         self._shed_overflow()
@@ -276,21 +290,14 @@ class Scheduler:
                           args={"step": self.steps,
                                 "policy": self.policy.name})
         try:
-            t_adm = time.perf_counter()
-            admitted = self._admit()
-            if tr is not None:
-                tr.complete("admit", time.perf_counter() - t_adm,
-                            cat="serve",
-                            args={"n_admitted": len(admitted),
-                                  "queue_depth": len(self.waiting)})
-            t_cmp = time.perf_counter()
-            plan = self.policy.compose(list(self.active.values()), self.cost,
-                                       max_batch=self.cfg.max_batch)
-            if tr is not None:
-                tr.complete(
-                    "compose", time.perf_counter() - t_cmp, cat="serve",
-                    args={"prefill_tokens": sum(n for _, n in plan.prefill),
-                          "decode_batch": len(plan.decode)})
+            with obs.maybe_span("serve.admit", cat="serve") as sp_adm:
+                admitted = self._admit()
+                sp_adm.set_metadata(admitted=len(admitted),
+                                    waiting=len(self.waiting))
+            with obs.maybe_span("serve.compose", cat="serve"):
+                plan = self.policy.compose(list(self.active.values()),
+                                           self.cost,
+                                           max_batch=self.cfg.max_batch)
             if plan.empty:
                 if self._arrivals:          # fast-forward to next arrival
                     if sp is not None:
@@ -298,12 +305,15 @@ class Scheduler:
                         tr.end(sp, dur_s=0.0)
                         sp = None           # closed; recursion owns its own
                     self.clock = self._arrivals[0][0]
-                    return self._step_impl(tr)
+                    return self._step_impl(tr, ann)
                 if sp is not None:
                     sp.args["idle"] = True
                     tr.end(sp, dur_s=0.0)
                 return None
 
+            prefill_tokens = sum(n for _, n in plan.prefill)
+            ann.set_metadata(prefill_tokens=prefill_tokens,
+                             decode_batch=len(plan.decode))
             prefill_entries = [(n, self.active[rid].prefill_pos)
                                for rid, n in plan.prefill]
             decode_ctx = [self.active[rid].context_len for rid in plan.decode]
@@ -359,7 +369,7 @@ class Scheduler:
                 queue_depth=len(self.waiting), active=len(self.active),
                 kv_blocks_used=self.blocks.used_blocks,
                 kv_blocks_total=self.blocks.num_blocks,
-                prefill_tokens=sum(n for _, n in plan.prefill),
+                prefill_tokens=prefill_tokens,
                 decode_batch=len(plan.decode))
             self._observe_step(rep)
             if tr is not None:
@@ -729,21 +739,29 @@ class ModelBackend:
         st = self._state[rs.rid]
         prompt = rs.req.prompt
         chunk = self.chunk_granularity(rs.prompt_len)
-        limit = self.max_cache_len
-        i, end = rs.prefill_pos, rs.prefill_pos + n
-        logits, caches = st["logits"], st["caches"]
-        while chunk > 1 and end - i >= chunk and i + chunk <= limit:
-            logits, caches = self._step(self.params, prompt[:, i:i + chunk],
-                                        caches, st["memory"])
-            i += chunk
-        for j in range(i, end):
-            logits, caches = self._step(self.params, prompt[:, j:j + 1],
-                                        caches, st["memory"])
-        st["logits"], st["caches"] = logits, caches
-        if end >= rs.prompt_len:           # prompt done: first token now
-            tok = self._sample(rs, logits)
-            tokens[rs.rid] = tok
-            st["next_tok"] = tok
+        start, end = rs.prefill_pos, rs.prefill_pos + n
+        # whole chunks that fit both the tokens and the cache, then one
+        # call per token
+        n_chunks = (max(0, min(n, self.max_cache_len - start)) // chunk
+                    if chunk > 1 else 0)
+        tail = start + n_chunks * chunk
+        args = {"rid": rs.rid, "tokens": n, "calls": n_chunks + end - tail}
+        if start == 0:
+            args["queued_s"] = time.perf_counter() - rs.submitted_wall_s
+        with obs.maybe_span("serve.prefill", cat="serve", **args):
+            logits, caches = st["logits"], st["caches"]
+            for i in range(start, tail, chunk):
+                logits, caches = self._step(
+                    self.params, prompt[:, i:i + chunk], caches,
+                    st["memory"])
+            for j in range(tail, end):
+                logits, caches = self._step(self.params, prompt[:, j:j + 1],
+                                            caches, st["memory"])
+            st["logits"], st["caches"] = logits, caches
+            if end >= rs.prompt_len:           # prompt done: first token now
+                tok = self._sample(rs, logits)
+                tokens[rs.rid] = tok
+                st["next_tok"] = tok
         return logits
 
     def _decode_batch(self, rids: Sequence[str],
@@ -769,28 +787,36 @@ class ModelBackend:
 
         n = len(rids)
         n_pad = 1 << (n - 1).bit_length()       # power-of-two batch bucket
-        toks = [jnp.asarray(self._state[r]["next_tok"],
-                            jnp.int32).reshape(1, 1) for r in rids]
-        caches = [self._state[r]["caches"] for r in rids]
-        if n_pad > n:
-            dummy = self._dummy_state()
-            toks += [dummy["tok"]] * (n_pad - n)
-            caches += [dummy["caches"]] * (n_pad - n)
-        stacked_t = jnp.stack(toks)             # (N, 1, 1)
-        stacked_c = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
-        vstep = self._vstep()
-        logits, new_c = vstep(self.params, stacked_t, stacked_c)
+        with obs.maybe_span("serve.decode.stack", cat="serve", batch=n,
+                            padded=n_pad):
+            toks = [jnp.asarray(self._state[r]["next_tok"],
+                                jnp.int32).reshape(1, 1) for r in rids]
+            caches = [self._state[r]["caches"] for r in rids]
+            if n_pad > n:
+                dummy = self._dummy_state()
+                toks += [dummy["tok"]] * (n_pad - n)
+                caches += [dummy["caches"]] * (n_pad - n)
+            stacked_t = jnp.stack(toks)             # (N, 1, 1)
+            stacked_c = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
+        with obs.maybe_span("serve.decode.step", cat="serve", padded=n_pad):
+            logits, new_c = self._vstep()(self.params, stacked_t, stacked_c)
+        with obs.maybe_span("serve.decode.unstack", cat="serve", batch=n):
+            for i, rid in enumerate(rids):
+                st = self._state[rid]
+                st["caches"] = jax.tree.map(lambda x, i=i: x[i], new_c)
+                st["logits"] = logits[i]
         out = {}
-        for i, rid in enumerate(rids):
-            st = self._state[rid]
-            st["caches"] = jax.tree.map(lambda x, i=i: x[i], new_c)
-            st["logits"] = logits[i]
-            tok = self._sample(states[rid], logits[i])
-            st["next_tok"] = tok
-            out[rid] = tok
+        with obs.maybe_span("serve.sample", cat="serve", batch=n):
+            for rid in rids:
+                st = self._state[rid]
+                tok = self._sample(states[rid], st["logits"])
+                st["next_tok"] = tok
+                out[rid] = tok
         return out
 
     def _vstep(self):
+        # the decode program's module is ``jit_step`` and the prefill's
+        # ``jit_serve_step``: the benchmark's trace readers match them
         import jax
 
         fn = self._vstep_cache.get(True)

@@ -16,19 +16,27 @@ chosen ``shard_map`` variant with the planned local kernels:
 Meshes and compiled executors are memoized per (grid, devices, variant,
 kernel), so a cache-hit call pays only plan lookup + padding + dispatch.
 
+Each call writes host spans into any profiler trace taken of the process
+(``repro.obs.maybe_span``): ``repro.linalg.<op>`` around the whole call,
+and inside it ``repro.dispatch.plan``, ``repro.dispatch.distribute``
+(padding and distribution) and ``repro.dispatch.execute`` (executor
+launch and the result slice).  They end when the work is launched, not
+when the device finishes it.
+
 When telemetry recording is on (``REPRO_TELEMETRY=1`` /
 ``repro.telemetry.enable()`` / per-call ``observe=True``) every dispatch
 emits one measured :class:`~repro.telemetry.RunRecord` with per-phase
 wall times (plan / distribute / execute, the execute phase blocked to
 completion) tagged by the plan's machine fingerprint — the raw material
-of the measured-run feedback loop.  With recording off the only added
-cost is one boolean check per call, and results stay unblocked.
+of the measured-run feedback loop.  Only that record blocks on the
+result; spans never do.
 """
 
 from __future__ import annotations
 
 import threading
 import time
+from contextlib import contextmanager
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
@@ -186,6 +194,24 @@ def executor(plan: ExecutionPlan, devices: Optional[Sequence] = None):
     return _executor(plan, mesh, devs, interpret), mesh
 
 
+@contextmanager
+def _phase(pt, plan: ExecutionPlan, name: str, **args):
+    """The ``repro.dispatch.<name>`` span, paired with the plan's
+    prediction for the phase; with a telemetry timer, the phase's wall
+    seconds are added to it as well."""
+    pred = plan.predicted.get(name)
+    if pred is None and name == "execute":
+        pred = plan.predicted.get("total")
+    with obs.maybe_span(f"dispatch.{name}", cat="dispatch",
+                        predicted_s=pred, **args):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            if pt is not None:
+                pt.add(name, time.perf_counter() - t0)
+
+
 def execute(plan: ExecutionPlan, *operands,
             devices: Optional[Sequence] = None, observe: bool = False,
             store=None, _plan_seconds: float = 0.0):
@@ -197,57 +223,57 @@ def execute(plan: ExecutionPlan, *operands,
     (default: the global default store).  ``_plan_seconds`` lets the
     model-guided wrappers account the planning time they already spent."""
     from .. import telemetry
-    from ..telemetry import phase_scope as _phase
     fn, mesh = executor(plan, devices)
     pt = None
-    if observe or telemetry.enabled() or obs.enabled():
+    if observe or telemetry.enabled():
         pt = telemetry.timer_for_plan(plan, kind="dispatch")
         if _plan_seconds > 0.0:
             pt.add("plan", _plan_seconds)
     n = plan.n
     g, c = plan.g, plan.c
-    # root span for the whole dispatch; the phase() children underneath
-    # (distribute/execute) carry the predicted durations and pair up
-    with obs.maybe_span(f"dispatch:{plan.algo}", cat="dispatch_root",
-                        algo=plan.algo, variant=plan.variant, n=n,
-                        p=plan.p, c=c,
-                        predicted_total_s=plan.predicted.get("total")):
+    with _phase(pt, plan, "distribute", algo=plan.algo):
         if plan.algo in ("cannon", "summa"):
             a, b = (jnp.asarray(x) for x in operands)
             m = _round_up(n, g)
-            with _phase(pt, "distribute"):
-                ad = distribute(_pad_zero(a, m, m), mesh, P("row", "col"))
-                bd = distribute(_pad_zero(b, m, m), mesh, P("row", "col"))
-            with _phase(pt, "execute"):
-                out = fn(ad, bd)[:n, :n]
-                if pt is not None:
-                    jax.block_until_ready(out)
+            args = (distribute(_pad_zero(a, m, m), mesh, P("row", "col")),
+                    distribute(_pad_zero(b, m, m), mesh, P("row", "col")))
         elif plan.algo == "trsm":
             u, b = (jnp.asarray(x) for x in operands)
             m = _round_up(n, g)
             mb = _round_up(n, c * g)
             bx_spec = P(("lyr", "row"), "col") if c > 1 else P("row", "col")
-            with _phase(pt, "distribute"):
-                ud = distribute(pad_eye(u, m), mesh, P("row", "col"))
-                bd = distribute(_pad_zero(b, mb, m), mesh, bx_spec)
-            with _phase(pt, "execute"):
-                out = fn(ud, bd)[:n, :n]
-                if pt is not None:
-                    jax.block_until_ready(out)
+            args = (distribute(pad_eye(u, m), mesh, P("row", "col")),
+                    distribute(_pad_zero(b, mb, m), mesh, bx_spec))
         elif plan.algo == "cholesky":
             (a,) = (jnp.asarray(x) for x in operands)
             m = _round_up(n, g)
-            with _phase(pt, "distribute"):
-                ad = distribute(pad_eye(a, m), mesh, P("row", "col"))
-            with _phase(pt, "execute"):
-                out = fn(ad)[:n, :n]
-                if pt is not None:
-                    jax.block_until_ready(out)
+            args = (distribute(pad_eye(a, m), mesh, P("row", "col")),)
         else:
             raise ValueError(f"unknown algo {plan.algo!r}")
+    with _phase(pt, plan, "execute", algo=plan.algo, variant=plan.variant,
+                g=g, c=c):
+        out = fn(*args)[:n, :n]
+        if pt is not None:
+            jax.block_until_ready(out)
     if pt is not None:
         pt.emit(store=store, force=observe)
     return out
+
+
+def _planned(op: str, n: int, operands, devices, tuner, local_kernel,
+             observe: bool):
+    """Plan ``op`` at size ``n`` and execute the plan, under the
+    ``repro.linalg.<op>`` span."""
+    t = tuner or default_tuner()
+    devs = list(devices) if devices is not None else jax.devices()
+    with obs.maybe_span(f"linalg.{op}", cat="dispatch_root", n=n):
+        t0 = time.perf_counter()
+        with obs.maybe_span("dispatch.plan", cat="dispatch", op=op, n=n):
+            plan = t.plan(op, n, devices=devs, dtype=_dtype_key(operands[0]),
+                          local_kernel=local_kernel, observe=observe)
+        return execute(plan, *operands, devices=devs, observe=observe,
+                       store=t.store,
+                       _plan_seconds=time.perf_counter() - t0)
 
 
 def matmul(A, B, *, devices: Optional[Sequence] = None,
@@ -259,14 +285,8 @@ def matmul(A, B, *, devices: Optional[Sequence] = None,
     n = _check_square("A", A)
     if tuple(B.shape) != tuple(A.shape):
         raise ValueError(f"A {A.shape} and B {B.shape} must match")
-    t = tuner or default_tuner()
-    devs = list(devices) if devices is not None else jax.devices()
-    t0 = time.perf_counter()
-    with obs.maybe_span("plan", cat="dispatch", op="matmul", n=n):
-        plan = t.plan("matmul", n, devices=devs, dtype=_dtype_key(A),
-                      local_kernel=local_kernel, observe=observe)
-    return execute(plan, A, B, devices=devs, observe=observe, store=t.store,
-                   _plan_seconds=time.perf_counter() - t0)
+    return _planned("matmul", n, (A, B), devices, tuner, local_kernel,
+                    observe)
 
 
 def trsm(U, B, *, devices: Optional[Sequence] = None,
@@ -277,14 +297,7 @@ def trsm(U, B, *, devices: Optional[Sequence] = None,
     n = _check_square("U", U)
     if tuple(B.shape) != tuple(U.shape):
         raise ValueError(f"U {U.shape} and B {B.shape} must match")
-    t = tuner or default_tuner()
-    devs = list(devices) if devices is not None else jax.devices()
-    t0 = time.perf_counter()
-    with obs.maybe_span("plan", cat="dispatch", op="trsm", n=n):
-        plan = t.plan("trsm", n, devices=devs, dtype=_dtype_key(U),
-                      local_kernel=local_kernel, observe=observe)
-    return execute(plan, U, B, devices=devs, observe=observe, store=t.store,
-                   _plan_seconds=time.perf_counter() - t0)
+    return _planned("trsm", n, (U, B), devices, tuner, local_kernel, observe)
 
 
 def cholesky(A, *, devices: Optional[Sequence] = None,
@@ -293,11 +306,5 @@ def cholesky(A, *, devices: Optional[Sequence] = None,
              observe: bool = False):
     """L with A = L L^T (A SPD), model-guided."""
     n = _check_square("A", A)
-    t = tuner or default_tuner()
-    devs = list(devices) if devices is not None else jax.devices()
-    t0 = time.perf_counter()
-    with obs.maybe_span("plan", cat="dispatch", op="cholesky", n=n):
-        plan = t.plan("cholesky", n, devices=devs, dtype=_dtype_key(A),
-                      local_kernel=local_kernel, observe=observe)
-    return execute(plan, A, devices=devs, observe=observe, store=t.store,
-                   _plan_seconds=time.perf_counter() - t0)
+    return _planned("cholesky", n, (A,), devices, tuner, local_kernel,
+                    observe)

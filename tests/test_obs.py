@@ -79,13 +79,16 @@ class TestSpans:
         assert unpaired.residual_s is None and unpaired.rel_err is None
 
     def test_maybe_span_disabled_is_shared_noop(self):
+        # off: the hook is the profiler annotation alone, named
+        # repro.<name>, and nothing enters the tracer's ring
+        from jax.profiler import TraceAnnotation
         obs.disable()
-        c1 = obs.maybe_span("a", cat="dispatch")
-        c2 = obs.maybe_span("b", cat="kernel")
-        assert c1 is c2                          # no allocation per call
-        with c1:
-            pass
+        c1 = obs.maybe_span("a", cat="dispatch", n=3)
+        assert isinstance(c1, TraceAnnotation)
+        with c1 as entered:
+            entered.set_metadata(done=1)
         assert obs.tracer().spans() == []
+        assert obs.tracer().n_closed == 0
 
     def test_alert_counts_and_marks(self):
         obs.enable()

@@ -13,6 +13,7 @@ test process that runs this file loads the TPU library.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -174,6 +175,36 @@ def test_blocked_factorization_n2048(one_chip, op):
     else:
         text = _compiled_text(lambda a: cholesky(a, interpret=False), x)
     assert text.count("tpu_custom_call") >= 2 * (2048 // 256) - 1
+
+
+def _kernel_names(text: str) -> set:
+    """The names of a compiled program's Pallas kernels, without their
+    numeric suffix (what a profiler trace names the operations)."""
+    return {re.sub(r"\.\d+$", "", m.group(1)) for m in re.finditer(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)}
+
+
+@pytest.mark.parametrize("kernel,want", [
+    ("matmul", {"matmul"}), ("trsm", {"trsm"}), ("cholesky", {"cholesky"}),
+    ("blocked_cholesky", {"matmul", "trsm", "cholesky"})])
+def test_kernel_names_in_the_compiled_program(one_chip, kernel, want):
+    """Each kernel keeps its name whatever function calls it: the trace
+    readers (``kernel.matmul_roofline``, ``kernel.diag_share``) find the
+    kernels by it."""
+    from repro.kernels import cholesky
+
+    x = _on(one_chip, (512, 512), jnp.float32)
+    fns = {"matmul": (lambda a, b: matmul_pallas(a, b, bm=256, bn=256,
+                                                 bk=256) * 2, (x, x)),
+           "trsm": (lambda u, b: trsm_diag_pallas(u, b) * 2,
+                    (_on(one_chip, (256, 256), jnp.float32),) * 2),
+           "cholesky": (lambda a: cholesky_block_pallas(a) * 2,
+                        (_on(one_chip, (256, 256), jnp.float32),)),
+           "blocked_cholesky": (lambda a: cholesky(a, interpret=False),
+                                (x,))}
+    fn, args = fns[kernel]
+    assert _kernel_names(_compiled_text(fn, *args)) == want
 
 
 def test_summa_2d_executor_with_pallas_locals(topo):
