@@ -1,7 +1,14 @@
 """Blocked triangular solve built from the diagonal-block kernel + the MXU
 matmul kernel: all O(n^3) off-diagonal work is dgemm-shaped.  Operands
 are padded (U identity-extended, B zero-extended) to the block, so every
-shape runs through the kernels."""
+shape runs through the kernels.
+
+The blocking is recursive halving (LAPACK's recursive TRSM): split the
+diagonal blocks in two, solve the left half, fold it into the right half
+with one dgemm of contraction width h, solve the right half.  The dgemms
+do the same FLOP as a right-looking loop over the blocks, but B is
+rewritten elementwise once per level, O(log nb) times, instead of once
+per block."""
 
 from __future__ import annotations
 
@@ -26,7 +33,8 @@ def trsm(u: jax.Array, b: jax.Array, *, block: int = 256,
     """Solve X U = B; U (n, n) upper-triangular, B (m, n).
 
     ``tiles`` (a trsm :class:`TilePlan`, dim ``block``) overrides the block
-    size; ``mm_tiles`` is threaded to the trailing-update dgemms.
+    size, the width of the diagonal solves; ``mm_tiles`` is threaded to
+    the update dgemms.  A single block is one diagonal kernel.
     ``interpret`` defaults to the platform (see ``resolve_interpret``).
     """
     interpret = resolve_interpret(interpret)
@@ -36,24 +44,16 @@ def trsm(u: jax.Array, b: jax.Array, *, block: int = 256,
     block = min(block, round_up(n0, MIN_TILE))
     u = pad_eye(u, round_up(n0, block))
     b = pad_axes(b, {0: MIN_TILE, 1: block})
-    n = u.shape[0]
-    m = b.shape[0]
-    nb = n // block
-    x_blocks = []
-    b_cur = b
-    for j in range(nb):
-        ujj = jax.lax.slice(u, (j * block, j * block),
-                            ((j + 1) * block, (j + 1) * block))
-        bj = jax.lax.slice(b_cur, (0, j * block), (m, (j + 1) * block))
-        xj = trsm_diag_pallas(ujj, bj, interpret=interpret)
-        x_blocks.append(xj)
-        if j + 1 < nb:
-            # trailing update: B_:,k -= X_:,j @ U_j,k  for k > j (one dgemm)
-            u_panel = jax.lax.slice(u, (j * block, (j + 1) * block),
-                                    ((j + 1) * block, n))
-            upd = matmul(xj, u_panel, interpret=interpret,
-                         out_dtype=b_cur.dtype, tiles=mm_tiles)
-            tail = jax.lax.slice(b_cur, (0, (j + 1) * block), (m, n)) - upd
-            b_cur = jnp.concatenate(
-                [jax.lax.slice(b_cur, (0, 0), (m, (j + 1) * block)), tail], axis=1)
-    return jnp.concatenate(x_blocks, axis=1)[:m0, :n0]
+
+    def solve(u, b):
+        nb = u.shape[0] // block
+        if nb == 1:
+            return trsm_diag_pallas(u, b, interpret=interpret)
+        h = (nb // 2) * block
+        x1 = solve(u[:h, :h], b[:, :h])
+        # B_2 -= X_1 U_12: one dgemm with contraction width h
+        b2 = b[:, h:] - matmul(x1, u[:h, h:], interpret=interpret,
+                               out_dtype=b.dtype, tiles=mm_tiles)
+        return jnp.concatenate([x1, solve(u[h:, h:], b2)], axis=1)
+
+    return solve(u, b)[:m0, :n0]

@@ -3,10 +3,11 @@
 TPU adaptation (DESIGN.md §3): a triangular solve's column recurrence maps
 poorly onto the MXU, so the kernel only performs the *diagonal-block*
 back-substitution (a ``bu x bu`` block held in VMEM, column loop on the
-VPU), while the ops.py wrapper blocks the full solve so that all O(n^3)
-off-diagonal work runs through the MXU matmul kernel.  This mirrors how
-LibSci's dtrsm spends its flops in dgemm-shaped updates (paper Fig. 1 shows
-dtrsm below dgemm efficiency for the same reason).
+VPU), while the ops.py wrapper solves the full system by recursive halving
+over the diagonal blocks, so that all O(n^3) off-diagonal work runs
+through the MXU matmul kernel.  This mirrors how LibSci's dtrsm spends
+its flops in dgemm-shaped updates (paper Fig. 1 shows dtrsm below dgemm
+efficiency for the same reason).
 
 The column loop is right-looking and touches no dynamic slice: column k
 of the working block and row k of U are picked out with iota masks and

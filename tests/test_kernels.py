@@ -1,13 +1,15 @@
 """Per-kernel allclose sweeps vs the pure-jnp oracles (interpret mode)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import (cholesky, cholesky_ref, flash_attention,
+from repro.kernels import (TilePlan, cholesky, cholesky_ref, flash_attention,
                            flash_attention_ref, matmul, matmul_ref, ssm_scan,
-                           ssm_scan_ref, trsm, trsm_ref)
+                           ssm_scan_ref, trsm, trsm_diag_pallas, trsm_ref)
 
 RNG = np.random.default_rng(42)
 
@@ -52,6 +54,31 @@ class TestTrsm:
         b = jnp.asarray(RNG.standard_normal((n, n)), jnp.float32)
         x = trsm(u, b)
         assert _rel(x @ u, b) < 1e-4
+
+    @pytest.mark.parametrize("nb,m", [(1, 200), (2, 384), (3, 200),
+                                      (5, 72), (8, 520)])
+    def test_recursive_blocking(self, nb, m):
+        """nb diagonal blocks of 128, split in halves (odd counts send the
+        remainder right); m need not be n or a multiple of 128."""
+        n = nb * 128
+        u = jnp.asarray(np.triu(RNG.standard_normal((n, n)))
+                        + 2 * np.sqrt(n) * np.eye(n), jnp.float32)
+        b = jnp.asarray(RNG.standard_normal((m, n)), jnp.float32)
+        x = trsm(u, b, tiles=TilePlan.make("trsm", block=128))
+        assert x.shape == (m, n)
+        assert _rel(x, trsm_ref(u, b)) < 1e-4
+
+    def test_single_block_is_the_diagonal_kernel(self):
+        """One block (Cholesky's panel solves) runs the diagonal kernel
+        alone: bit for bit what the kernel returns."""
+        n, m = 128, 384
+        u = jnp.asarray(np.triu(RNG.standard_normal((n, n)))
+                        + 2 * np.sqrt(n) * np.eye(n), jnp.float32)
+        b = jnp.asarray(RNG.standard_normal((m, n)), jnp.float32)
+        got = trsm(u, b, tiles=TilePlan.make("trsm", block=128))
+        want = jax.jit(functools.partial(trsm_diag_pallas,
+                                         interpret=True))(u, b)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 class TestCholesky:

@@ -177,6 +177,35 @@ def test_blocked_factorization_n2048(one_chip, op):
     assert text.count("tpu_custom_call") >= 2 * (2048 // 256) - 1
 
 
+def test_trsm_recursive_blocking_n4096(one_chip):
+    """The blocked TRSM at n = 4096 with the plan's tiles (block 128;
+    matmul 512/1024/256) halves recursively: 32 diagonal kernels, 31
+    update dgemms contracting over 2048 once, 1024 twice, ... 128 sixteen
+    times, and few elementwise passes over B.  XLA's bytes accessed were
+    3.75e9 (55.9 m·n·4) for the right-looking loop over the blocks and
+    1.24e9 (18.5 m·n·4) for recursive halving; the bound sits between."""
+    from repro.kernels import TilePlan, trsm
+
+    n = 4096
+    x = _on(one_chip, (n, n), jnp.float32)
+    compiled = jax.jit(lambda u, b: trsm(
+        u, b, interpret=False, tiles=TilePlan.make("trsm", block=128),
+        mm_tiles=TilePlan.make("matmul", bm=512, bn=1024, bk=256))).lower(
+            x, x).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\w+)[.\d]* = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\", operand_layout_constraints="
+                       r"\{\w+\[(\d+),(\d+)\]", text)
+    assert sum(name == "trsm" for name, _, _ in calls) == 32
+    contractions = sorted((int(k) for name, _, k in calls
+                           if name == "matmul"), reverse=True)
+    assert contractions == [n // 2 ** (i + 1) for i in range(5)
+                            for _ in range(2 ** i)]
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 32 * n * n * 4
+
+
 def _kernel_names(text: str) -> set:
     """The names of a compiled program's Pallas kernels, without their
     numeric suffix (what a profiler trace names the operations)."""
