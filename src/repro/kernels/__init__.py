@@ -8,7 +8,7 @@ sweeps).
 
 from .common import TilePlan, heuristic_plan, pad_axes, round_up
 from .matmul import matmul, matmul_pallas, matmul_ref
-from .trsm import trsm, trsm_diag_pallas, trsm_ref
+from .trsm import trsm, trsm_diag_inv_pallas, trsm_diag_pallas, trsm_ref
 from .cholesky import cholesky, cholesky_block_pallas, cholesky_ref
 from .flash_attention import (flash_attention, flash_attention_pallas,
                               flash_attention_ref)

@@ -1,12 +1,15 @@
 """Blocked Cholesky (right-looking) composed from all three linalg kernels:
 diagonal factor (cholesky kernel), panel solve (trsm kernel: L_ij L_jj^T =
 A_ij), trailing syrk update (matmul kernel).  A is identity-extended to
-the block, so every shape runs through the kernels."""
+the block, so every shape runs through the kernels.  Each panel solve
+is the trsm wrapper on one diagonal block, L_jj^T: panels with many rows
+against the block invert L_jj^T once and apply it on the MXU, the last,
+short panels back-substitute (``trsm.ops.diag_split``)."""
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -14,8 +17,18 @@ import jax.numpy as jnp
 from ..common import (MIN_TILE, TilePlan, pad_eye, resolve_interpret,
                       round_up, tile_block)
 from ..matmul.ops import matmul
-from ..trsm.ops import trsm
+from ..trsm.ops import diag_split as trsm_diag_split, trsm
 from .cholesky import cholesky_block_pallas
+
+
+def diag_split(n: int, block: int = 256) -> Tuple[int, int]:
+    """(inverted, substituted) over the panel solves of ``cholesky`` on A
+    (n, n) at ``block``: ``trsm.ops.diag_split`` of each panel's rows."""
+    block = min(block, round_up(n, MIN_TILE))
+    n = round_up(n, block)
+    splits = [trsm_diag_split(n - jj, block, block)
+              for jj in range(block, n, block)]
+    return sum(s[0] for s in splits), sum(s[1] for s in splits)
 
 
 @functools.partial(jax.jit,

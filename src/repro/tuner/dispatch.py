@@ -21,7 +21,10 @@ Each call writes host spans into any profiler trace taken of the process
 and inside it ``repro.dispatch.plan``, ``repro.dispatch.distribute``
 (padding and distribution) and ``repro.dispatch.execute`` (executor
 launch and the result slice).  They end when the work is launched, not
-when the device finishes it.
+when the device finishes it.  With Pallas locals, a TRSM or Cholesky
+execute span carries ``diag_inverted`` / ``diag_substituted``: how many
+diagonal blocks one device's local solves invert and apply on the MXU,
+and how many they back-substitute, in one call (from shapes alone).
 
 When telemetry recording is on (``REPRO_TELEMETRY=1`` /
 ``repro.telemetry.enable()`` / per-call ``observe=True``) every dispatch
@@ -45,8 +48,10 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.cholesky.ops import cholesky as _kchol
+from ..kernels.cholesky.ops import diag_split as _chol_split
 from ..kernels.common import TilePlan, pad_eye
 from ..kernels.matmul.ops import matmul as _kmm
+from ..kernels.trsm.ops import diag_split as _trsm_split
 from ..kernels.trsm.ops import trsm as _ktrsm
 # NB: import the factories, not the modules — the linalg package shadows
 # the trsm/cholesky module attributes with the dispatch wrappers.
@@ -194,6 +199,29 @@ def executor(plan: ExecutionPlan, devices: Optional[Sequence] = None):
     return _executor(plan, mesh, devs, interpret), mesh
 
 
+def _diag_args(plan: ExecutionPlan) -> dict:
+    """``diag_inverted`` / ``diag_substituted`` of the plan's local Pallas
+    solves on one device in one call, by the kernels' own shape rule: each
+    of the g steps solves a local (rows, edge) block, and on a grid a
+    Cholesky step also solves its panel against L_jj^T at the wrapper's
+    default block (on one device that solve is masked out, and dead)."""
+    if plan.local_kernel != "pallas" or plan.algo not in ("trsm",
+                                                          "cholesky"):
+        return {}
+    g, c = plan.g, plan.c
+    edge = _round_up(plan.n, g) // g
+    block = (plan.tiles or {}).get(plan.algo, {}).get("block", 256)
+    if plan.algo == "trsm":
+        rows = _round_up(plan.n, c * g) // (c * g)
+        split = _trsm_split(rows, edge, block)
+    else:
+        split = _chol_split(edge, block)
+        if g > 1:
+            panel = _trsm_split(edge, edge)
+            split = (split[0] + panel[0], split[1] + panel[1])
+    return {"diag_inverted": g * split[0], "diag_substituted": g * split[1]}
+
+
 @contextmanager
 def _phase(pt, plan: ExecutionPlan, name: str, **args):
     """The ``repro.dispatch.<name>`` span, paired with the plan's
@@ -251,7 +279,7 @@ def execute(plan: ExecutionPlan, *operands,
         else:
             raise ValueError(f"unknown algo {plan.algo!r}")
     with _phase(pt, plan, "execute", algo=plan.algo, variant=plan.variant,
-                g=g, c=c):
+                g=g, c=c, **_diag_args(plan)):
         out = fn(*args)[:n, :n]
         if pt is not None:
             jax.block_until_ready(out)

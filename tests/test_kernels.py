@@ -9,7 +9,10 @@ import pytest
 
 from repro.kernels import (TilePlan, cholesky, cholesky_ref, flash_attention,
                            flash_attention_ref, matmul, matmul_ref, ssm_scan,
-                           ssm_scan_ref, trsm, trsm_diag_pallas, trsm_ref)
+                           ssm_scan_ref, trsm, trsm_diag_inv_pallas,
+                           trsm_diag_pallas, trsm_ref)
+from repro.kernels.cholesky import ops as chol_ops
+from repro.kernels.trsm import ops as trsm_ops
 
 RNG = np.random.default_rng(42)
 
@@ -69,9 +72,10 @@ class TestTrsm:
         assert _rel(x, trsm_ref(u, b)) < 1e-4
 
     def test_single_block_is_the_diagonal_kernel(self):
-        """One block (Cholesky's panel solves) runs the diagonal kernel
-        alone: bit for bit what the kernel returns."""
-        n, m = 128, 384
+        """One block with fewer rows than twice its width (Cholesky's last
+        panels) runs the diagonal kernel alone: bit for bit what the
+        kernel returns."""
+        n, m = 128, 128
         u = jnp.asarray(np.triu(RNG.standard_normal((n, n)))
                         + 2 * np.sqrt(n) * np.eye(n), jnp.float32)
         b = jnp.asarray(RNG.standard_normal((m, n)), jnp.float32)
@@ -79,6 +83,72 @@ class TestTrsm:
         want = jax.jit(functools.partial(trsm_diag_pallas,
                                          interpret=True))(u, b)
         assert np.array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("n,m,block", [(128, 384, 128), (256, 1000, 128),
+                                           (300, 700, 128), (640, 1500, 256),
+                                           (100, 2000, 128)])
+    def test_inverted_diagonal_blocks(self, n, m, block):
+        """m >> block: each diagonal block is inverted once and applied on
+        the MXU, within the substitution's tolerance; n and m need not be
+        multiples of the block."""
+        assert trsm_ops.diag_split(m, n, block)[1] == 0
+        u = jnp.asarray(np.triu(RNG.standard_normal((n, n)))
+                        + 2 * np.sqrt(n) * np.eye(n), jnp.float32)
+        b = jnp.asarray(RNG.standard_normal((m, n)), jnp.float32)
+        x = trsm(u, b, tiles=TilePlan.make("trsm", block=block))
+        assert x.shape == (m, n)
+        assert _rel(x, trsm_ref(u, b)) < 1e-4
+
+    @pytest.mark.parametrize("m,n,block,want", [
+        (16384, 16384, 128, (128, 0)),   # the linalg cell's TRSM
+        (256, 1024, 128, (8, 0)),        # m = 2b: inverted
+        (129, 1024, 128, (8, 0)),        # rows are padded to 256 first
+        (128, 1024, 128, (0, 8)),        # m = b: substituted
+        (384, 512, 256, (0, 2)),
+        (1000, 300, 512, (1, 0)),        # block capped at the edge, 384
+        (600, 300, 512, (0, 1))])
+    def test_diag_split_is_what_the_wrapper_does(self, monkeypatch, m, n,
+                                                 block, want):
+        """The rule switches at m = 2b on padded shapes, and the wrapper's
+        trace calls the kernels it says: one batched inversion covering
+        every block, or one substitution per block."""
+        assert trsm_ops.diag_split(m, n, block) == want
+        seen = [0, 0]
+
+        def inv(u, *, block, **kw):
+            seen[0] += u.shape[0] // block
+            return trsm_diag_inv_pallas(u, block=block, **kw)
+
+        def sub(u, b, **kw):
+            seen[1] += 1
+            return trsm_diag_pallas(u, b, **kw)
+
+        monkeypatch.setattr(trsm_ops, "trsm_diag_inv_pallas", inv)
+        monkeypatch.setattr(trsm_ops, "trsm_diag_pallas", sub)
+        jax.eval_shape(functools.partial(trsm_ops.trsm.__wrapped__,
+                                         block=block),
+                       jax.ShapeDtypeStruct((n, n), jnp.float32),
+                       jax.ShapeDtypeStruct((m, n), jnp.float32))
+        assert tuple(seen) == want
+
+    @pytest.mark.parametrize("n,block", [(512, 128), (1024, 512)])
+    @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
+    def test_block_inverse_is_upper_triangular(self, n, block, dt):
+        """Each diagonal block's inverse is exactly zero below its diagonal
+        (block 512 is two row blocks, the second starting at column 256)
+        and inverts its block."""
+        u = np.triu(RNG.standard_normal((n, n))) + 2 * np.sqrt(n) * np.eye(n)
+        inv = np.asarray(trsm_diag_inv_pallas(jnp.asarray(u, dt),
+                                              block=block, interpret=True),
+                         np.float32)
+        assert inv.shape == (n, block)
+        tol = 2e-2 if dt == jnp.bfloat16 else 1e-5
+        for i in range(n // block):
+            blk = inv[i * block:(i + 1) * block]
+            assert np.all(np.tril(blk, -1) == 0)
+            ui = np.asarray(jnp.asarray(u, dt), np.float32)[
+                i * block:(i + 1) * block, i * block:(i + 1) * block]
+            assert np.abs(blk @ ui - np.eye(block)).max() < tol
 
 
 class TestCholesky:
@@ -91,6 +161,24 @@ class TestCholesky:
         assert _rel(l @ l.T, a) < 1e-4
         # strictly-lower triangular
         assert np.allclose(np.triu(np.asarray(l), 1), 0)
+
+    @pytest.mark.parametrize("n,block,want", [(16384, 512, (30, 1)),
+                                              (1024, 256, (2, 1)),
+                                              (700, 128, (4, 1)),
+                                              (512, 256, (0, 1))])
+    def test_panels_by_the_trsm_rule(self, n, block, want):
+        """Every panel solve but the short last ones inverts L_jj^T."""
+        assert chol_ops.diag_split(n, block) == want
+
+    @pytest.mark.parametrize("n,block", [(1024, 256), (700, 128)])
+    def test_inverted_panels_keep_l_lower(self, n, block):
+        """On the inverse path L is exactly zero above its diagonal."""
+        assert chol_ops.diag_split(n, block)[0] > 0
+        m = RNG.standard_normal((n, n))
+        a = jnp.asarray(m @ m.T + n * np.eye(n), jnp.float32)
+        l = cholesky(a, tiles=TilePlan.make("cholesky", block=block))
+        assert np.all(np.triu(np.asarray(l), 1) == 0)
+        assert _rel(l, cholesky_ref(a)) < 1e-4
 
 
 class TestFlashAttention:

@@ -22,7 +22,7 @@ from jax.sharding import (NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.kernels import (cholesky_block_pallas, matmul_pallas,
-                           trsm_diag_pallas)
+                           trsm_diag_inv_pallas, trsm_diag_pallas)
 
 
 @pytest.fixture(scope="module")
@@ -135,16 +135,23 @@ def test_matmul_largest_admitted_tiles_compile(one_chip, dtype, tile):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("kernel", ["trsm", "cholesky"])
+@pytest.mark.parametrize("kernel", ["trsm", "trsm_inverse", "cholesky"])
 @pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
 def test_diag_largest_admitted_block_compiles(one_chip, kernel, dtype):
-    """The largest diagonal block the VMEM gate admits compiles."""
-    shape = (16384, 16384) if kernel == "trsm" else (16384,)
-    ((nb,),) = _largest_admitted(kernel, shape, jnp.dtype(dtype).itemsize)
+    """The largest diagonal block the VMEM gate admits compiles, as a
+    substitution, as a batched inversion of two blocks, and as a
+    factor."""
+    shape = (16384,) if kernel == "cholesky" else (16384, 16384)
+    ((nb,),) = _largest_admitted(kernel.split("_")[0], shape,
+                                 jnp.dtype(dtype).itemsize)
     u = _on(one_chip, (nb, nb), dtype)
     if kernel == "trsm":
         text = _compiled_text(trsm_diag_pallas, u, _on(one_chip, (1024, nb),
                                                         dtype))
+    elif kernel == "trsm_inverse":
+        text = _compiled_text(
+            lambda u: trsm_diag_inv_pallas(u, block=nb),
+            _on(one_chip, (2 * nb, 2 * nb), dtype))
     else:
         text = _compiled_text(cholesky_block_pallas, u)
     assert "tpu_custom_call" in text
@@ -177,13 +184,25 @@ def test_blocked_factorization_n2048(one_chip, op):
     assert text.count("tpu_custom_call") >= 2 * (2048 // 256) - 1
 
 
+def _custom_calls(text: str) -> list:
+    """(kernel name, first operand's rows, its columns) of each Pallas
+    kernel in a compiled program."""
+    return [(name, int(r), int(k)) for name, r, k in re.findall(
+        r"%(\w+)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\", "
+        r"operand_layout_constraints=\{\w+\[(\d+),(\d+)\]", text)]
+
+
 def test_trsm_recursive_blocking_n4096(one_chip):
     """The blocked TRSM at n = 4096 with the plan's tiles (block 128;
-    matmul 512/1024/256) halves recursively: 32 diagonal kernels, 31
-    update dgemms contracting over 2048 once, 1024 twice, ... 128 sixteen
-    times, and few elementwise passes over B.  XLA's bytes accessed were
-    3.75e9 (55.9 m·n·4) for the right-looking loop over the blocks and
-    1.24e9 (18.5 m·n·4) for recursive halving; the bound sits between."""
+    matmul 512/1024/256) halves recursively and inverts its diagonal
+    blocks: one batched ``trsm`` kernel inverts all 32 (it reads U), 32
+    matmuls of contraction 128 apply the inverses, and 31 update dgemms
+    contract over 2048 once, 1024 twice, ... 128 sixteen times.  Before
+    the inverses there were 32 ``trsm`` substitutions over every row of B
+    and the 31 dgemms.  XLA's bytes accessed were 3.75e9 (55.9 m·n·4) for
+    the right-looking loop over the blocks and 1.24e9 (18.5 m·n·4) for
+    recursive halving, with or without the inverses: the applies add no
+    elementwise pass over B."""
     from repro.kernels import TilePlan, trsm
 
     n = 4096
@@ -192,18 +211,36 @@ def test_trsm_recursive_blocking_n4096(one_chip):
         u, b, interpret=False, tiles=TilePlan.make("trsm", block=128),
         mm_tiles=TilePlan.make("matmul", bm=512, bn=1024, bk=256))).lower(
             x, x).compile()
-    text = compiled.as_text()
-    calls = re.findall(r"%(\w+)[.\d]* = [^\n]*custom_call_target="
-                       r"\"tpu_custom_call\", operand_layout_constraints="
-                       r"\{\w+\[(\d+),(\d+)\]", text)
-    assert sum(name == "trsm" for name, _, _ in calls) == 32
-    contractions = sorted((int(k) for name, _, k in calls
-                           if name == "matmul"), reverse=True)
-    assert contractions == [n // 2 ** (i + 1) for i in range(5)
-                            for _ in range(2 ** i)]
+    calls = _custom_calls(compiled.as_text())
+    assert [c for c in calls if c[0] == "trsm"] == [("trsm", n, n)]
+    contractions = sorted((k for name, _, k in calls if name == "matmul"),
+                          reverse=True)
+    dgemms = [n // 2 ** (i + 1) for i in range(5) for _ in range(2 ** i)]
+    assert contractions == sorted(dgemms + [128] * 32, reverse=True)
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
-    assert cost["bytes accessed"] < 32 * n * n * 4
+    assert cost["bytes accessed"] < 24 * n * n * 4
+
+
+@pytest.mark.parametrize("rows,inverted", [(15872, True), (512, False)])
+def test_cholesky_panel_solve(one_chip, rows, inverted):
+    """One panel of Cholesky at n = 16384, block 512, as its wrapper
+    calls it: the first panel (15,872 rows) inverts L_jj^T in one
+    ``trsm`` kernel on its 512 rows and applies it in one matmul of
+    contraction 512; the last (512 rows) substitutes every row."""
+    from repro.kernels import TilePlan, trsm
+
+    u = _on(one_chip, (512, 512), jnp.float32)
+    b = _on(one_chip, (rows, 512), jnp.float32)
+    text = _compiled_text(lambda u, b: trsm(
+        u, b, block=512, interpret=False,
+        mm_tiles=TilePlan.make("matmul", bm=512, bn=1024, bk=256)), u, b)
+    calls = _custom_calls(text)
+    if inverted:
+        assert calls.count(("trsm", 512, 512)) == 1
+        assert calls.count(("matmul", rows, 512)) == 1 and len(calls) == 2
+    else:
+        assert calls == [("trsm", 512, 512)]
 
 
 def _kernel_names(text: str) -> set:

@@ -253,3 +253,75 @@ class TestDispatchMultiDevice:
 
     def test_dispatched_variant_matches_select(self, verdicts):
         assert verdicts["plan_matches_select"] is True
+
+
+class TestDispatchDiagSpan:
+    """``repro.dispatch.execute`` carries how many diagonal blocks the
+    local Pallas solves invert and how many they substitute, from the
+    kernels' shape rule."""
+
+    @staticmethod
+    def _v5e_plan(tuner, op, n=16384, **grid):
+        import dataclasses
+        plan = tuner.plan(op, n, device_count=1, platform="tpu",
+                          device_kind="TPU v5 lite", local_kernel="pallas",
+                          use_cache=False)
+        return dataclasses.replace(plan, **grid) if grid else plan
+
+    @pytest.mark.parametrize("op,want", [("trsm", (128, 0)),
+                                         ("cholesky", (30, 1))])
+    def test_execute_span_carries_diag_split(self, tmp_path, op, want):
+        """The linalg cell's plans (TRSM block 128, Cholesky block 512)
+        read 128 / 0 and 30 / 1; a call on the CPU carries its own plan's
+        counts on its execute span."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from repro import obs
+        from repro.tuner import dispatch
+
+        tuner = Tuner(cache=PlanCache(str(tmp_path / "plans")))
+        big = self._v5e_plan(tuner, op)
+        blocks = {"trsm": 128, "cholesky": 512}
+        assert big.tiles[op]["block"] == blocks[op]
+        assert dispatch._diag_args(big) == {"diag_inverted": want[0],
+                                            "diag_substituted": want[1]}
+        n = 768
+        rng = np.random.default_rng(0)
+        m = rng.standard_normal((n, n))
+        if op == "trsm":
+            operands = (np.triu(m) + 2 * np.sqrt(n) * np.eye(n), m)
+        else:
+            operands = (m @ m.T + n * np.eye(n),)
+        operands = [jnp.asarray(x, jnp.float32) for x in operands]
+        obs.reset()
+        tr = obs.enable()
+        try:
+            jax.block_until_ready(getattr(dispatch, op)(
+                *operands, tuner=tuner, local_kernel="pallas"))
+            (span,) = [s for s in tr.spans() if s.name == "dispatch.execute"]
+        finally:
+            obs.reset()
+        plan = tuner.plan(op, n, devices=jax.devices(),
+                          local_kernel="pallas")
+        args = dispatch._diag_args(plan)
+        assert args["diag_inverted"] > 0
+        assert {k: span.args[k] for k in args} == args
+
+    @pytest.mark.parametrize("op,want", [("trsm", (128, 0)),
+                                         ("cholesky", (92, 2))])
+    def test_diag_split_per_device_on_a_grid(self, tmp_path, op, want):
+        """On a 2x2 grid (2.5D for TRSM: rows over two layers) each of the
+        g = 2 steps solves a local 8192-wide block; a Cholesky step adds
+        its panel solve at block 256 (32 blocks, all inverted) to its
+        local factor's 15 panels."""
+        from repro.tuner import dispatch
+
+        tuner = Tuner(cache=PlanCache(str(tmp_path / "plans")))
+        grid = {"g": 2, "c": 2 if op == "trsm" else 1}
+        plan = self._v5e_plan(tuner, op, **grid)
+        assert dispatch._diag_args(plan) == {"diag_inverted": want[0],
+                                             "diag_substituted": want[1]}
+        jnp_plan = self._v5e_plan(tuner, op, local_kernel="jnp")
+        assert dispatch._diag_args(jnp_plan) == {}
