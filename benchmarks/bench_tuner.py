@@ -111,7 +111,6 @@ def main() -> dict:
 
     # --- auto-selected vs worst feasible variant ---------------------------
     from repro.tuner.autotune import feasible_grids
-    from repro.core import predictor
     ctx = tuner.registry.context(plan.machine)
     worst_plan, worst_total = None, -1.0
     for algo in ("cannon", "summa"):

@@ -34,9 +34,10 @@ def main() -> dict:
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.core import (AlgoContext, CommModel, CalibrationTable,
-                            evaluate)
+    from repro.core import AlgoContext, CommModel, CalibrationTable
     from repro.perf import EvalOptions
+    from repro.tuner import DEFAULT_REGISTRY
+    evaluate = DEFAULT_REGISTRY.evaluate
     from repro.core.calibration import (bench_ping, fit_alpha_beta,
                                         measured_compute_model)
     from repro.linalg import ALGORITHMS, distribute

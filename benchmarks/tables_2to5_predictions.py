@@ -8,15 +8,14 @@ import json
 def main() -> dict:
     import numpy as np
     from repro.core import AlgoContext, CommModel, ComputeModel, TPU_V5E
-    from repro.core.algorithms import ALGOS, USEFUL_FLOPS, VARIANTS
-    from repro.core.calibration import (hopper_fitted_ctx,
-                                        joint_validation_report)
+    from repro.perf import ALGOS
+    from repro.tuner.paper_fit import hopper_fitted_ctx, joint_validation_report
     from repro.sim import derive_calibration, v5e_pod_topology
     from repro.core.machine import HOPPER
     from repro.core.paper_data import (CLAIMED_CROSSOVER, CORE_COUNTS,
                                        PAPER_TABLES, table_best_variant)
     from repro.core.perfmodel import TPU_EFFICIENCY
-    from repro.core.predictor import best_variant, crossover_core_count, \
+    from repro.tuner.select import best_variant, crossover_core_count, \
         prediction_table
 
     ctx = hopper_fitted_ctx()

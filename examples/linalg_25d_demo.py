@@ -66,9 +66,9 @@ def main():
 
     # and the model's advice for real machines
     from repro.core import AlgoContext, CommModel, ComputeModel, TPU_V5E
-    from repro.core.calibration import hopper_fitted_ctx
     from repro.core.perfmodel import TPU_EFFICIENCY
-    from repro.core.predictor import select
+    from repro.tuner.paper_fit import hopper_fitted_ctx
+    from repro.tuner.select import select
     from repro.sim import derive_calibration, v5e_pod_topology
     ctx_h = hopper_fitted_ctx()
     ch = select(ctx_h, "cholesky", 65536, 4096)

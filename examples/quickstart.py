@@ -25,8 +25,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import numpy as np
 
 from repro.core import AlgoContext, CommModel, ComputeModel, HOPPER
-from repro.core.calibration import hopper_fitted_ctx
-from repro.core.predictor import best_variant, format_table, prediction_table
+from repro.tuner.paper_fit import hopper_fitted_ctx
+from repro.tuner.select import best_variant, format_table, prediction_table
 
 
 def author_a_model_demo(ctx):

@@ -5,33 +5,30 @@ train/serve steps on TPU meshes).
 Layout:
   machine.py      machine constants (Hopper Cray XE6, TPU v5e, CPU host)
   perfmodel.py    alpha-beta + calibration-factor primitives (paper §IV)
+                  and AlgoContext, the surfaces a model evaluation reads
   collectives.py  analytic collective models (paper §V, scalar closed forms)
-  algorithms.py   scalar shims over the cost-IR algorithm models (§V);
-                  the models themselves are authored in repro.perf.models
   calibration.py  portable benchmarks + fitting (paper §IV, Figs. 1-4)
-  predictor.py    variant selection + prediction tables (paper §VI),
-                  batched through the vectorized cost-IR evaluator
+  paper_data.py   the paper's published tables (§VI)
+  fitting.py      derivative-free fitting helpers
   roofline.py     3-term TPU roofline from compiled HLO (§Roofline)
   hlo.py          structural HLO parsing (trip-count-corrected costs)
   lm_model.py     the methodology applied to LM steps (beyond-paper)
 
-The cost-IR itself (nodes, symbolic scenario parameters, the vectorized
-evaluator) lives in the sibling package ``repro.perf``.
+This is the bottom layer: imports point one way, core -> perf -> tuner.
+The algorithm models are cost-IR programs in ``repro.perf``; variant
+selection and the prediction tables (§VI) are ``repro.tuner.select``.
+``lm_model`` is the one exception: it reads ``repro.sim`` and
+``repro.configs``.
 """
 
 from .machine import (CPU_HOST, HOPPER, KernelConstants, MACHINES, TPU_V5E,
                       Machine)
-from .perfmodel import (CalibrationTable, CommModel, ComputeModel,
-                        EfficiencyCurve, IdentityCalibration,
+from .perfmodel import (AlgoContext, CalibrationTable, CommModel,
+                        ComputeModel, EfficiencyCurve, IdentityCalibration,
                         ParametricCalibration)
-from .algorithms import (ALGOS, VARIANTS, AlgoContext, ModelResult, evaluate,
-                         pct_of_peak)
-from .predictor import best_variant, prediction_table, select
 
 __all__ = [
     "CPU_HOST", "HOPPER", "KernelConstants", "MACHINES", "TPU_V5E", "Machine",
-    "CalibrationTable", "CommModel", "ComputeModel", "EfficiencyCurve",
-    "IdentityCalibration", "ParametricCalibration",
-    "ALGOS", "VARIANTS", "AlgoContext", "ModelResult", "evaluate",
-    "pct_of_peak", "best_variant", "prediction_table", "select",
+    "AlgoContext", "CalibrationTable", "CommModel", "ComputeModel",
+    "EfficiencyCurve", "IdentityCalibration", "ParametricCalibration",
 ]

@@ -2,7 +2,8 @@
 
 The paper fits a polynomial regression to extrapolate ``C_max`` beyond the
 largest measured core count; we additionally fit parametric calibration
-surfaces to published table data (see ``calibration.fit_hopper_calibration``).
+surfaces to published table data (see
+``repro.tuner.paper_fit.fit_hopper_calibration``).
 scipy is not available offline, so we carry a small Nelder--Mead and a
 least-squares polynomial fit on plain numpy.
 """

@@ -373,6 +373,14 @@ class ComputeModel:
         return math.ceil(big / small) * self.t_rout(rout, small, t)
 
 
+@dataclasses.dataclass
+class AlgoContext:
+    """The machine surfaces every algorithm-model evaluation reads."""
+
+    comm: CommModel
+    comp: ComputeModel
+
+
 def hopper_compute_model() -> ComputeModel:
     from .machine import HOPPER
     return ComputeModel(HOPPER, HOPPER_EFFICIENCY)

@@ -13,12 +13,15 @@ first-class:
   evaluate.py  the evaluator: calibration applied in exactly one place,
                est_Cal / est_NoCal / est_ideal chosen by EvalOptions,
                vectorized over numpy grids of scenarios
-  models.py    the paper's 16 variants + LU 2D/2.5D as IR programs
+  models.py    the paper's 16 variants + LU 2D/2.5D as IR programs, the
+               ALGOS x VARIANTS matrix and each algorithm's useful flops
+  kernel.py    the intra-kernel tier: tile plans for the local Pallas
+               kernels
 
-Scalar call sites keep working through ``repro.core.algorithms`` shims;
-batch consumers (``core.predictor``, ``repro.tuner``) evaluate whole
-scenario grids in one pass via ``evaluate_program`` /
-``PerfModelRegistry.evaluate_grid``.
+Only ``repro.core`` sits below this package.  Selection over the programs
+(``repro.tuner.select``, ``repro.tuner.Tuner``) evaluates whole scenario
+grids in one pass via ``evaluate_program``; ``ModelResult`` is the
+scalar view of one scenario.
 """
 
 from .expr import (C, D, Expr, N, P, Param, Q, R, T, as_expr, floor, fmax,
@@ -26,11 +29,11 @@ from .expr import (C, D, Expr, N, P, Param, Q, R, T, as_expr, floor, fmax,
 from .ir import (COLLECTIVE_KINDS, Collective, Compute, Loop, Node, Overlap,
                  P2P, Program, Seq, SyncP2P)
 from .evaluate import (EVAL_MODES, CollectiveStep, EvalOptions, EvalResult,
-                       MODEL_VERSION, PhaseCost, collective_schedule,
-                       evaluate_program)
-from .models import PROGRAMS, USEFUL_FLOPS, build_programs, lu_2d, lu_25d
-# kernel imports repro.core.machine; keep it LAST so the attributes above
-# exist if core's import of this package re-enters mid-initialization.
+                       MODEL_VERSION, ModelResult, PhaseCost,
+                       collective_schedule, evaluate_program, pct_of_peak,
+                       result_from_eval)
+from .models import (ALGOS, PROGRAMS, USEFUL_FLOPS, VARIANTS, build_programs,
+                     lu_2d, lu_25d)
 from .kernel import (ALGO_KERNELS, CANDIDATE_SIZES, KERNEL_DIMS, KernelModel,
                      KernelPhases, KernelWork, MIN_TILE, TilePlan,
                      VMEM_BUDGET, candidate_tiles, heuristic_matmul_blocks,
@@ -42,8 +45,10 @@ __all__ = [
     "COLLECTIVE_KINDS", "Collective", "Compute", "Loop", "Node", "Overlap",
     "P2P", "Program", "Seq", "SyncP2P",
     "EVAL_MODES", "CollectiveStep", "EvalOptions", "EvalResult",
-    "MODEL_VERSION", "PhaseCost", "collective_schedule", "evaluate_program",
-    "PROGRAMS", "USEFUL_FLOPS", "build_programs", "lu_2d", "lu_25d",
+    "MODEL_VERSION", "ModelResult", "PhaseCost", "collective_schedule",
+    "evaluate_program", "pct_of_peak", "result_from_eval",
+    "ALGOS", "PROGRAMS", "USEFUL_FLOPS", "VARIANTS", "build_programs",
+    "lu_2d", "lu_25d",
     "ALGO_KERNELS", "CANDIDATE_SIZES", "KERNEL_DIMS", "KernelModel",
     "KernelPhases", "KernelWork", "MIN_TILE", "TilePlan", "VMEM_BUDGET",
     "candidate_tiles", "heuristic_matmul_blocks", "heuristic_plan",

@@ -22,8 +22,11 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..core.perfmodel import ROUTINE_FLOPS, AlgoContext
 from .ir import (Collective, Compute, Loop, Node, Overlap, P2P, Program, Seq,
                  SyncP2P)
+from .kernel import KernelModel
+from .models import USEFUL_FLOPS
 
 #: bump when model semantics change incompatibly — consumers (the plan
 #: cache) embed this so predictions from older equations are invalidated.
@@ -65,9 +68,8 @@ class PhaseCost:
 
 @dataclasses.dataclass
 class EvalResult:
-    """Structured evaluation output (supersedes the ad-hoc ``terms`` dict
-    of the pre-IR ``ModelResult``): totals plus a per-phase breakdown, all
-    broadcast to the scenario grid's shape."""
+    """Structured evaluation output: totals plus a per-phase breakdown,
+    all broadcast to the scenario grid's shape."""
 
     total: np.ndarray
     comm: np.ndarray
@@ -79,14 +81,52 @@ class EvalResult:
         return {name: ph.exposed for name, ph in self.phases.items()}
 
 
+@dataclasses.dataclass
+class ModelResult:
+    """One scenario's estimated seconds, with a comm/comp decomposition.
+
+    ``comm``/``comp`` are the *serialized* sums of each class of term;
+    ``total`` accounts for overlap (max-composition), so
+    ``total <= comm + comp`` always holds.  ``terms`` is the scalar view of
+    the per-phase breakdown (:attr:`EvalResult.phases`).
+    """
+
+    total: float
+    comm: float
+    comp: float
+    terms: Dict[str, float]
+    algo: str = ""
+    variant: str = ""
+    n: int = 0
+    p: int = 0
+    c: int = 1
+    r: int = 1
+
+
+def result_from_eval(program: Program, res: EvalResult, n, p, c, r,
+                     idx=None) -> ModelResult:
+    """One cell of ``res`` as a :class:`ModelResult`, echoing only the
+    tuning parameters the model reads.  ``idx`` selects one cell of a
+    vectorized result; ``None`` reads a 0-d (scalar) result."""
+    pick = float if idx is None else (lambda a: float(a[idx]))
+    return ModelResult(
+        pick(res.total), pick(res.comm), pick(res.comp),
+        {name: pick(ph.exposed) for name, ph in res.phases.items()},
+        algo=program.algo, variant=program.variant, n=n, p=p,
+        c=c if program.uses_c else 1, r=r if program.uses_r else 1)
+
+
+def pct_of_peak(ctx: AlgoContext, res: ModelResult) -> float:
+    """Percentage of machine peak achieved (the paper's reporting metric)."""
+    flops = USEFUL_FLOPS[res.algo](res.n)
+    peak = res.p * ctx.comp.machine.peak_flops_per_unit
+    return 100.0 * flops / (res.total * peak)
+
+
 class _Evaluator:
     """One walk of a program against (machine surface, env, options)."""
 
     def __init__(self, ctx, env: Dict[str, np.ndarray], options: EvalOptions):
-        # Imported here, not at module top: repro.core.algorithms imports
-        # repro.perf for its shims, so a top-level core import would cycle.
-        from ..core.perfmodel import ROUTINE_FLOPS
-        self.routine_flops = ROUTINE_FLOPS
         self.env = env
         self.options = options
         comm = ctx.comm
@@ -101,7 +141,6 @@ class _Evaluator:
         if options.kernel_tier and \
                 getattr(self.comp_machine, "kernel_constants", None) \
                 is not None:
-            from .kernel import KernelModel
             self.kernel_model = KernelModel(self.comp_machine)
         self.phases: Dict[str, PhaseCost] = {}
 
@@ -139,7 +178,7 @@ class _Evaluator:
             return np.where(block > 0, t_k, 0.0)
         t = m.threads_per_unit if threads is None else threads
         t = np.clip(t, 1, m.threads_per_unit)
-        flops = self.routine_flops[routine](block)
+        flops = ROUTINE_FLOPS[routine](block)
         eff = self.efficiency[routine].ev(block)
         out = flops / (m.peak_flops_per_thread * t * eff)
         return np.where(block > 0, out, 0.0)

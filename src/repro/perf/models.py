@@ -1,10 +1,9 @@
 """The paper's 16 algorithm-variant models (§V) — and LU — as IR programs.
 
-Each program is a declarative transcription of the closed-form model it
-replaces (``core.algorithms`` pre-IR; golden-pinned by
-``tests/golden/model_values.json``): the same terms in the same order, with
-the transcription deviations documented in DESIGN.md §1 carried over
-verbatim.  Loop bounds are the paper's collapsed closed-form sums
+Each program is a declarative transcription of the paper's closed-form
+model (golden-pinned by ``tests/golden/model_values.json``): the same
+terms in the same order, with the transcription deviations documented in
+DESIGN.md §1 carried over verbatim.  Loop bounds are the paper's collapsed closed-form sums
 (``sum_decreasing`` etc.), so evaluation stays O(1) per scenario and
 vectorizes over ``(n, p, c, r)`` grids.
 
@@ -22,6 +21,11 @@ from typing import Dict, Tuple
 from .expr import (C, N, P, R, T, fmax, rint, sqrt, sum_decreasing,
                    sum_squares)
 from .ir import Collective, Compute, Loop, Overlap, P2P, Program, Seq, SyncP2P
+
+#: the paper's algorithm/variant matrix (LU is a beyond-paper addition and
+#: is deliberately not listed here; enumerate the registry for everything)
+ALGOS = ("cannon", "summa", "trsm", "cholesky")
+VARIANTS = ("2d", "2d_ovlp", "2.5d", "2.5d_ovlp")
 
 #: useful flops of each algorithm at global size n (the paper's %-of-peak
 #: numerator); numpy-compatible like ROUTINE_FLOPS.

@@ -1,7 +1,8 @@
 """repro.tuner — model-guided autotuning dispatch.
 
 The paper's headline application, closed end-to-end: the analytic
-performance models (``repro.core``) select the 2D/2.5D ±overlap variant,
+performance models (cost-IR programs in ``repro.perf`` over the machine
+surfaces of ``repro.core``) select the 2D/2.5D ±overlap variant,
 replication factor and grid for a scenario, and the executable
 ``shard_map`` algorithms (``repro.linalg``) run the winner with the Pallas
 kernels (``repro.kernels``) as local compute.
@@ -9,6 +10,9 @@ kernels (``repro.kernels``) as local compute.
 Layout:
   registry.py   PerfModelRegistry — one query surface over the algorithm
                 models, collective models, and machine surfaces
+  select.py     candidate scoring (one vectorized evaluation per model) +
+                the paper's §VI variant selection and prediction tables
+  paper_fit.py  fits the Hopper surfaces to the paper's published tables
   plan.py       ExecutionPlan + persistent JSON PlanCache (artifacts/plans/)
   autotune.py   Tuner — feasible-grid enumeration + model selection +
                 LM-layer consultations (fsdp layout, prefill chunking)

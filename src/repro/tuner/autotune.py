@@ -7,9 +7,9 @@ on this device pool?" by
    realize (2D ``g x g`` grids and 2.5D ``c x g x g`` grids — the
    executable 2.5D matmuls need ``c | g``, and replication is capped at
    ``c <= g`` so every layer owns work);
-2. evaluating every candidate (algo, variant, c) through the registry's
-   analytic models via ``core.predictor`` — the paper's §VI selection,
-   restricted to realizable configurations;
+2. scoring every candidate (algo, variant, c) through the registry's
+   cost-IR programs with ``select.score_candidates`` — the paper's §VI
+   selection, restricted to realizable configurations;
 3. freezing the argmin into an :class:`ExecutionPlan` and persisting it in
    the plan cache, so the next call with the same (machine fingerprint,
    op, n, p, dtype) never touches the models again.
@@ -33,11 +33,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..core import predictor
-from ..core.algorithms import result_from_eval
+from ..perf import pct_of_peak
 from ..perf.kernel import tiles_for_plan
 from .plan import (ExecutionPlan, PlanCache, machine_fingerprint, plan_key)
 from .registry import DEFAULT_REGISTRY, PerfModelRegistry, machine_for_platform
+from .select import Candidate, fits_memory, score_candidates
 
 #: public operation -> candidate algorithm models (matmul races Cannon
 #: against SUMMA; the factorizations map one-to-one).  "lu" plans through
@@ -128,15 +128,11 @@ class Tuner:
         """
         if refine not in (None, "sim"):
             raise ValueError(f"refine must be None or 'sim', got {refine!r}")
+        if devices is None and device_count is None:
+            import jax
+            devices = jax.devices()
         if devices is not None:
             devices = list(devices)
-            device_count = len(devices)
-            platform = platform or devices[0].platform
-            device_kind = device_kind or getattr(devices[0], "device_kind",
-                                                 platform)
-        if device_count is None:
-            import jax
-            devices = list(jax.devices())
             device_count = len(devices)
             platform = platform or devices[0].platform
             device_kind = device_kind or getattr(devices[0], "device_kind",
@@ -148,11 +144,11 @@ class Tuner:
         # simulator: only the per-rank engine sees link granularity, so
         # closed-form-only planning would ignore the fault entirely.
         try:
-            _surface = self.registry.machine(machine)
+            surface = self.registry.machine(machine)
         except KeyError:
-            _surface = None
-        if refine is None and _surface is not None \
-                and getattr(_surface, "faults", None) is not None:
+            surface = None
+        if refine is None and surface is not None \
+                and surface.faults is not None:
             refine = "sim"
         if local_kernel not in (None, "pallas", "jnp"):
             raise ValueError(f"local_kernel must be 'pallas' or 'jnp', "
@@ -162,10 +158,7 @@ class Tuner:
         # Key plans by the registered Machine *profile* (its fingerprint
         # hashes every field, incl. the telemetry-bumped revision), not the
         # bare name — refits and drift invalidation change the key.
-        try:
-            profile = self.registry.machine(machine).machine
-        except KeyError:
-            profile = machine
+        profile = surface.machine if surface is not None else machine
         fp = machine_fingerprint(profile, platform, device_kind, device_count)
         # refine and shortlist both shape the refined decision, so they are
         # part of the cache identity (closed-form plans keep their old keys)
@@ -219,72 +212,47 @@ class Tuner:
             raise ValueError(f"unknown op {op!r}; known: {sorted(OP_ALGOS)}") \
                 from None
         ctx = self.registry.context(machine)
-        # Enumerate every realizable (algo, variant, p, c, g) candidate in
-        # selection-priority order, then score them with ONE vectorized
-        # model evaluation per (algo, variant) instead of a scalar
-        # predictor.select call per grid (the executables use r=1).
-        cands: List[Tuple[str, str, int, int, int]] = []
+        # Every realizable (algo, variant, n, p, c, r=1) candidate in
+        # selection-priority order (the executables use r=1); the argmin
+        # total over all of them is the plan.
+        cands: List[Candidate] = []
         for algo in algos:
             all_variants = self.registry.variants(algo)
-            for p, c, g in feasible_grids(device_count, algo):
+            for p, c, _g in feasible_grids(device_count, algo):
                 kind = "2d" if c == 1 else "2.5d"
                 for variant in all_variants:
                     if not variant.startswith(kind):
                         continue
-                    if variant.startswith("2.5d") and \
-                            not predictor.fits_memory(ctx, algo, n, p, c):
+                    if c > 1 and not fits_memory(ctx, algo, n, p, c):
                         continue  # replication at this c exceeds memory
-                    cands.append((algo, variant, p, c, g))
+                    cands.append((algo, variant, n, p, c, 1))
         if not cands:
             raise ValueError(f"no feasible grid for {device_count} devices")
-        totals = np.empty(len(cands))
-        evals: Dict[Tuple[str, str], tuple] = {}
-        groups: Dict[Tuple[str, str], List[int]] = {}
-        for j, (algo, variant, p, c, g) in enumerate(cands):
-            groups.setdefault((algo, variant), []).append(j)
-        for (algo, variant), idx in groups.items():
-            ps = np.array([cands[j][2] for j in idx], dtype=float)
-            cs = np.array([cands[j][3] for j in idx], dtype=float)
-            if self.registry.has_program(algo, variant):
-                res = self.registry.evaluate_grid(ctx, algo, variant,
-                                                  float(n), ps, cs, 1.0)
-                evals[(algo, variant)] = (res, idx)
-                totals[idx] = res.total
-            else:  # legacy scalar ModelFn without a program
-                for j in idx:
-                    totals[j] = self.registry.evaluate(
-                        ctx, algo, variant, n, cands[j][2], c=cands[j][3]).total
+        totals, result = score_candidates(ctx, cands, registry=self.registry)
         sim_extra: Optional[Dict[str, float]] = None
         if refine == "sim":
-            j, sim_extra = self._sim_rerank(cands, totals, machine, n,
+            j, sim_extra = self._sim_rerank(cands, totals, machine,
                                             shortlist, device_count)
         else:
             j = int(np.argmin(totals))
-        algo, variant, p, c, g = cands[j]
-        ev = evals.get((algo, variant))
-        if ev is not None:
-            res = result_from_eval(self.registry.program(algo, variant),
-                                   ev[0], n, p, c, 1, idx=ev[1].index(j))
-        else:
-            res = self.registry.evaluate(ctx, algo, variant, n, p, c=c)
+        algo, _variant, _n, p, c, _r = cands[j]
+        g = math.isqrt(p // c)      # feasible grids have p = c * g * g
+        res = result(j)
         predicted = {"total": res.total, "comm": res.comm, "comp": res.comp,
-                     "pct_peak": predictor.pct_of_peak(ctx, res)}
+                     "pct_peak": pct_of_peak(ctx, res)}
         if sim_extra is not None:
             predicted.update(sim_extra)
         # the intra-kernel tier: per-family tile plans for the local Pallas
         # kernels this algo will run — model-chosen when the machine profile
         # has kernel constants, today's heuristic blocks otherwise
-        try:
-            profile = self.registry.machine(machine).machine
-        except KeyError:
-            profile = None
-        tiles = tiles_for_plan(profile, algo, n, g, dtype)
+        tiles = tiles_for_plan(self.registry.machine(machine).machine, algo,
+                               n, g, dtype)
         return ExecutionPlan(
             algo=algo, variant=res.variant, n=n, p=p, c=c, r=res.r, g=g,
             local_kernel=local_kernel, dtype=dtype, machine=machine,
             fingerprint=fp, predicted=predicted, tiles=tiles)
 
-    def _sim_rerank(self, cands, totals, machine: str, n: int,
+    def _sim_rerank(self, cands, totals, machine: str,
                     shortlist: int, device_count: Optional[int] = None
                     ) -> Tuple[int, Dict[str, float]]:
         """The opt-in second planning stage: replay the closed-form top-k
@@ -308,25 +276,22 @@ class Tuner:
         topo = None
         if faults is not None and device_count is not None:
             topo = topology_for(surface.machine, device_count)
-        order = np.argsort(totals)[:max(1, int(shortlist))]
-        picked = [int(j) for j in order
-                  if self.registry.has_program(*cands[int(j)][:2])]
-        # legacy scalar models cannot be simulated; they drop out here
+        picked = [int(j) for j in np.argsort(totals)[:max(1, int(shortlist))]]
         programs = [self.registry.program(*cands[j][:2]) for j in picked]
-        scens = [{"n": float(n), "p": cands[j][2], "c": cands[j][3], "r": 1}
-                 for j in picked]
+        scens = [{"n": float(n), "p": p, "c": c, "r": r}
+                 for _a, _v, n, p, c, r in (cands[j] for j in picked)]
         sims = simulate_programs(programs, ctx, scens,
                                  machine=surface.machine, topology=topo,
                                  faults=faults, strict=(faults is None))
         with self._lock:
             self.stats["sim_evals"] = self.stats.get("sim_evals", 0) \
                 + len(sims)
-        best_j, best_t = int(order[0]), float("inf")
+        best_j, best_t = picked[0], float("inf")
         extras: Dict[str, float] = {}
         for j, sim in zip(picked, sims):
             if sim is None:
                 continue  # e.g. unreachable under dead links
-            algo, variant, p, c, _g = cands[j]
+            algo, variant, _n, p, c, _r = cands[j]
             extras[f"sim/{algo}/{variant}@p{p}c{c}"] = float(sim.total)
             if sim.total < best_t:
                 best_j, best_t = j, float(sim.total)

@@ -1,26 +1,20 @@
 """One query surface over every analytic performance model in the repo.
 
-Before this module existed the model layers were islands: the 16
-algorithm-variant models lived in ``core.algorithms.MODELS``, the collective
-models were free functions in ``core.collectives``, and the machine /
-calibration surfaces were assembled ad hoc at every call site
-(``AlgoContext(CommModel(HOPPER, ...), ComputeModel(HOPPER, ...))``).  The
-``PerfModelRegistry`` unifies them:
+The ``PerfModelRegistry`` holds:
 
 * **algorithm models** — ``(algo, variant) -> Program`` (cost-IR, see
   ``repro.perf``) with registration, enumeration, scalar ``evaluate`` and
-  vectorized ``evaluate_grid``; plain scalar ``ModelFn`` registration is
-  kept as a legacy path;
+  vectorized ``evaluate_grid``;
 * **collective models** — name -> analytic collective, so consumers (the
   tuner benchmark, the LM-step models) can enumerate and cross-check them;
 * **machine surfaces** — machine constants + routine-efficiency curves +
   contention calibration bundled per machine name, with ``context()``
   building the ``AlgoContext`` every model evaluation needs.
 
-``core.predictor`` sits on top of this registry (it no longer hard-codes
-the ALGOS/VARIANTS tuples), and ``repro.tuner.autotune`` uses it to plan
-end-to-end execution.  ``DEFAULT_REGISTRY`` is pre-populated with
-everything the repo ships; tests may build private registries.
+``repro.tuner.select`` enumerates whatever this registry holds, and
+``repro.tuner.autotune`` uses it to plan end-to-end execution.
+``DEFAULT_REGISTRY`` is pre-populated with everything the repo ships;
+tests may build private registries.
 """
 
 from __future__ import annotations
@@ -28,14 +22,14 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, Mapping, Optional, Sequence, Tuple
 
-from ..core import algorithms as alg
 from ..core import collectives as coll
-from ..core.machine import CPU_HOST, HOPPER, MACHINES, TPU_V5E, Machine
-from ..core.perfmodel import (Calibration, CommModel, ComputeModel,
-                              EfficiencyCurve, HOPPER_EFFICIENCY,
-                              ParametricCalibration, TPU_EFFICIENCY)
-from ..perf import EvalOptions, EvalResult, Program, evaluate_program
-from ..perf.models import PROGRAMS
+from ..core.machine import CPU_HOST, HOPPER, TPU_V5E, Machine
+from ..core.perfmodel import (AlgoContext, Calibration, CommModel,
+                              ComputeModel, EfficiencyCurve,
+                              HOPPER_EFFICIENCY, ParametricCalibration,
+                              TPU_EFFICIENCY)
+from ..perf import (PROGRAMS, EvalOptions, EvalResult, ModelResult, Program,
+                    evaluate_program, result_from_eval)
 
 
 @dataclasses.dataclass
@@ -57,10 +51,10 @@ class MachineSurface:
     calibration: Calibration
     faults: Optional[object] = None
 
-    def context(self, calibration: Optional[Calibration] = None) -> alg.AlgoContext:
+    def context(self, calibration: Optional[Calibration] = None) -> AlgoContext:
         cal = calibration if calibration is not None else self.calibration
-        return alg.AlgoContext(comm=CommModel(self.machine, cal),
-                               comp=ComputeModel(self.machine, self.efficiency))
+        return AlgoContext(comm=CommModel(self.machine, cal),
+                           comp=ComputeModel(self.machine, self.efficiency))
 
 
 class PerfModelRegistry:
@@ -68,32 +62,19 @@ class PerfModelRegistry:
     surfaces behind one query interface."""
 
     def __init__(self):
-        self._algo_models: Dict[Tuple[str, str], alg.ModelFn] = {}
         self._programs: Dict[Tuple[str, str], Program] = {}
         self._collectives: Dict[str, Callable] = {}
         self._machines: Dict[str, MachineSurface] = {}
 
     # -- registration --------------------------------------------------------
-    def register_algorithm(self, algo: str, variant: str, fn: alg.ModelFn,
-                           *, overwrite: bool = False) -> None:
-        """Register a plain scalar ModelFn (legacy path: no vectorized
-        evaluation; batch consumers fall back to per-scenario calls).
-        Prefer :meth:`register_program`."""
-        key = (algo, variant)
-        if key in self._algo_models and not overwrite:
-            raise ValueError(f"model for {key} already registered")
-        self._algo_models[key] = fn
-
     def register_program(self, program: Program,
                          *, overwrite: bool = False) -> None:
-        """Register a cost-IR :class:`~repro.perf.Program`: the model gains
-        vectorized grid evaluation and a scalar shim in one step."""
+        """Register a cost-IR :class:`~repro.perf.Program` as the model of
+        its ``(algo, variant)``."""
         key = program.key
-        if (key in self._algo_models or key in self._programs) \
-                and not overwrite:
+        if key in self._programs and not overwrite:
             raise ValueError(f"model for {key} already registered")
         self._programs[key] = program
-        self._algo_models[key] = alg.scalar_shim(program)
 
     def register_collective(self, name: str, fn: Callable,
                             *, overwrite: bool = False) -> None:
@@ -114,21 +95,14 @@ class PerfModelRegistry:
 
     # -- queries -------------------------------------------------------------
     def algos(self) -> Tuple[str, ...]:
-        return tuple(dict.fromkeys(a for a, _ in self._algo_models))
+        return tuple(dict.fromkeys(a for a, _ in self._programs))
 
     def variants(self, algo: str) -> Tuple[str, ...]:
-        out = tuple(v for a, v in self._algo_models if a == algo)
+        out = tuple(v for a, v in self._programs if a == algo)
         if not out:
             raise KeyError(f"no models registered for algo {algo!r} "
                            f"(have: {self.algos()})")
         return out
-
-    def model(self, algo: str, variant: str) -> alg.ModelFn:
-        try:
-            return self._algo_models[(algo, variant)]
-        except KeyError:
-            raise KeyError(f"no model for ({algo!r}, {variant!r}); "
-                           f"registered: {sorted(self._algo_models)}") from None
 
     def has_program(self, algo: str, variant: str) -> bool:
         return (algo, variant) in self._programs
@@ -157,19 +131,21 @@ class PerfModelRegistry:
         return tuple(self._machines)
 
     def context(self, machine: str,
-                calibration: Optional[Calibration] = None) -> alg.AlgoContext:
+                calibration: Optional[Calibration] = None) -> AlgoContext:
         return self.machine(machine).context(calibration)
 
     # -- evaluation ----------------------------------------------------------
-    def evaluate(self, ctx: alg.AlgoContext, algo: str, variant: str,
+    def evaluate(self, ctx: AlgoContext, algo: str, variant: str,
                  n: int, p: int, c: int = 1, r: int = 1,
-                 options: Optional[EvalOptions] = None) -> alg.ModelResult:
-        fn = self.model(algo, variant)
-        if options is not None:
-            return fn(ctx, n, p, c=c, r=r, options=options)
-        return fn(ctx, n, p, c=c, r=r)
+                 options: Optional[EvalOptions] = None) -> ModelResult:
+        """Scalar evaluation of one scenario.  ``options`` selects the
+        estimator flavor (est_Cal / est_NoCal / est_ideal) without
+        rebuilding the context — see :class:`repro.perf.EvalOptions`."""
+        program = self.program(algo, variant)
+        res = evaluate_program(program, ctx, n, p, c, r, options=options)
+        return result_from_eval(program, res, n, p, c, r)
 
-    def evaluate_grid(self, ctx: alg.AlgoContext, algo: str, variant: str,
+    def evaluate_grid(self, ctx: AlgoContext, algo: str, variant: str,
                       n, p, c=1, r=1,
                       options: Optional[EvalOptions] = None) -> EvalResult:
         """Vectorized evaluation over numpy arrays of scenarios — one pass

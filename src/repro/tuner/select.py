@@ -7,32 +7,33 @@ block-cyclic factors ``r``) and pick the fastest — including the memory
 constraint that 2.5D replication must fit ("our models ... can take into
 account runtime constraints (e.g., available memory)").
 
-The model surface itself lives in ``repro.tuner.registry``
-(``PerfModelRegistry``): this module no longer hard-codes the
-ALGOS/VARIANTS tuples but enumerates whatever the registry holds, so
-registering a new algorithm model makes it selectable here (and by the
-end-to-end autotuner in ``repro.tuner``) with no further changes.
+The models live in ``repro.tuner.registry`` (``PerfModelRegistry``): this
+module enumerates whatever the registry holds, so registering a new
+algorithm model makes it selectable here (and by the end-to-end autotuner
+in ``repro.tuner.autotune``) with no further changes.
 
-Selection is *batched*: every public entry point collects its whole
-candidate set — (scenario, variant, c, r) tuples across all table cells —
-and makes one vectorized cost-IR evaluation per variant
-(``PerfModelRegistry.evaluate_grid``) instead of one scalar model call per
-candidate.  ``prediction_table`` reproduces the structure of paper
-Tables II-V (percentage-of-peak for each variant over a grid of core
-counts and sizes).
+Selection is *batched*: every entry point collects its whole candidate
+set — (algo, variant, n, p, c, r) tuples — and scores it with
+``score_candidates``, one vectorized cost-IR evaluation per (algo,
+variant).  The paper's tables enumerate (c, r) per cell; the tuner
+enumerates the grids its device pool can realize; both score here.
+``prediction_table`` reproduces the structure of paper Tables II-V
+(percentage-of-peak for each variant over a grid of core counts and
+sizes).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..perf import EvalOptions
-from .algorithms import (USEFUL_FLOPS, AlgoContext, ModelResult, pct_of_peak,
-                         result_from_eval)
+from ..core.perfmodel import AlgoContext
+from ..perf import (USEFUL_FLOPS, EvalOptions, ModelResult, pct_of_peak,
+                    result_from_eval)
+from .registry import DEFAULT_REGISTRY, PerfModelRegistry
 
 #: matrices resident per algorithm (A,B,C for matmul; X/B + U for trsm;
 #: A for chol; A in-place for LU)
@@ -43,17 +44,10 @@ _MATRICES = {"cannon": 3.0, "summa": 3.0, "trsm": 2.0, "cholesky": 1.0,
 _NEEDS_R = ("trsm", "cholesky", "lu")
 
 
-def _registry():
-    """The unified model registry (lazy import: core must stay importable
-    without the tuner package, and tuner imports core)."""
-    from ..tuner.registry import DEFAULT_REGISTRY
-    return DEFAULT_REGISTRY
-
-
 def fits_memory(ctx: AlgoContext, algo: str, n: int, p: int, c: int) -> bool:
     """Does c-way replication of the algorithm's resident matrices fit the
     per-process memory?  The single feasibility predicate shared by the
-    predictor and the end-to-end tuner."""
+    paper's tables and the end-to-end tuner."""
     words = _MATRICES.get(algo, 3.0) * float(n) * n * c / p
     return words * ctx.comm.machine.word_bytes <= ctx.comp.machine.mem_per_unit
 
@@ -81,14 +75,47 @@ class VariantChoice:
     pct_peak: float
 
 
+#: one scenario to score: (algo, variant, n, p, c, r)
+Candidate = Tuple[str, str, int, int, int, int]
+
+
+def score_candidates(ctx: AlgoContext, cands: Sequence[Candidate], *,
+                     registry: Optional[PerfModelRegistry] = None,
+                     options: Optional[EvalOptions] = None,
+                     ) -> Tuple[np.ndarray, Callable[[int], ModelResult]]:
+    """Score every candidate with one vectorized evaluation per (algo,
+    variant).  Returns the predicted ``total`` seconds per candidate and a
+    function expanding candidate ``j`` into its :class:`ModelResult`."""
+    reg = registry or DEFAULT_REGISTRY
+    totals = np.empty(len(cands))
+    cell: Dict[int, tuple] = {}     # j -> (program, EvalResult, position)
+    groups: Dict[Tuple[str, str], List[int]] = {}
+    for j, cand in enumerate(cands):
+        groups.setdefault(cand[:2], []).append(j)
+    for (algo, variant), idx in groups.items():
+        arr = np.array([cands[j][2:] for j in idx], dtype=float)
+        res = reg.evaluate_grid(ctx, algo, variant, arr[:, 0], arr[:, 1],
+                                arr[:, 2], arr[:, 3], options=options)
+        totals[idx] = res.total
+        program = reg.program(algo, variant)
+        for k, j in enumerate(idx):
+            cell[j] = (program, res, k)
+
+    def result(j: int) -> ModelResult:
+        program, res, k = cell[j]
+        return result_from_eval(program, res, *cands[j][2:], idx=k)
+
+    return totals, result
+
+
 def _cell_candidates(ctx: AlgoContext, algo: str, variant: str, n: int,
                      p: int, r_values: Sequence[int], max_c: Optional[int],
                      c_values: Optional[Sequence[int]],
                      needs_r: bool) -> Optional[List[Tuple[int, int]]]:
     """(c, r) candidates for one (cell, variant), with the memory filter
-    and fallback policy of the scalar-era ``best_variant``; ``None`` means
-    the variant is infeasible under pinned ``c_values`` and must be
-    dropped (an over-memory config must *lose*, not be re-scored)."""
+    and the OOM fallback policy; ``None`` means the variant is infeasible
+    under pinned ``c_values`` and must be dropped (an over-memory config
+    must *lose*, not be re-scored)."""
     if variant.startswith("2d"):
         cs = [1]
     elif c_values is not None:
@@ -129,51 +156,30 @@ def best_variant_batch(ctx: AlgoContext, algo: str,
     a variant infeasible for a cell (memory, under pinned ``c_values``) is
     absent from that cell's dict.
     """
-    reg = registry or _registry()
+    reg = registry or DEFAULT_REGISTRY
     needs_r = algo in _NEEDS_R
     variant_list = (tuple(variants) if variants is not None
                     else reg.variants(algo))
-    out: List[Dict[str, VariantChoice]] = [dict() for _ in cells]
+    owner: List[Tuple[int, str]] = []       # (cell index, variant) per cand
+    cands: List[Candidate] = []
     for variant in variant_list:
-        idx: List[int] = []
-        cand: List[Tuple[int, int, int, int]] = []   # (n, p, c, r)
         for ci, (n, p) in enumerate(cells):
             cs = _cell_candidates(ctx, algo, variant, n, p, r_values, max_c,
                                   c_values, needs_r)
-            if cs is None:
-                continue
-            for c, r in cs:
-                idx.append(ci)
-                cand.append((n, p, c, r))
-        if not idx:
-            continue
-        program = reg.program(algo, variant) \
-            if reg.has_program(algo, variant) else None
-        scalars: List[ModelResult] = []
-        if program is not None:
-            arr = np.array(cand, dtype=float)
-            res = reg.evaluate_grid(ctx, algo, variant, arr[:, 0], arr[:, 1],
-                                    arr[:, 2], arr[:, 3], options=options)
-            totals = res.total
-        else:
-            # legacy ModelFn registered without a program: scalar fallback
-            # (options are forwarded so estimator flavors stay consistent
-            # across variants; a legacy fn that cannot accept them fails
-            # loudly rather than silently mixing est_Cal with est_NoCal)
-            scalars = [reg.evaluate(ctx, algo, variant, n, p, c=c, r=r,
-                                    options=options)
-                       for (n, p, c, r) in cand]
-            totals = np.array([m.total for m in scalars])
-        best_j: Dict[int, int] = {}
-        for j, ci in enumerate(idx):
-            b = best_j.get(ci)
-            if b is None or totals[j] < totals[b]:
-                best_j[ci] = j
-        for ci, j in best_j.items():
-            n, p, c, r = cand[j]
-            mr = (scalars[j] if program is None
-                  else result_from_eval(program, res, n, p, c, r, idx=j))
-            out[ci][variant] = VariantChoice(mr, pct_of_peak(ctx, mr))
+            for c, r in cs or ():
+                owner.append((ci, variant))
+                cands.append((algo, variant, n, p, c, r))
+    totals, result = score_candidates(ctx, cands, registry=reg,
+                                      options=options)
+    best_j: Dict[Tuple[int, str], int] = {}
+    for j, key in enumerate(owner):
+        b = best_j.get(key)
+        if b is None or totals[j] < totals[b]:
+            best_j[key] = j
+    out: List[Dict[str, VariantChoice]] = [dict() for _ in cells]
+    for (ci, variant), j in best_j.items():
+        mr = result(j)
+        out[ci][variant] = VariantChoice(mr, pct_of_peak(ctx, mr))
     return out
 
 
@@ -185,9 +191,9 @@ def best_variant(ctx: AlgoContext, algo: str, n: int, p: int,
                  registry=None) -> Dict[str, VariantChoice]:
     """Evaluate every variant, tuning (c, r); returns {variant: best choice}.
 
-    ``c_values`` overrides the legal-c enumeration for 2.5D variants (the
-    end-to-end tuner passes the replication factors its device pool can
-    actually realize); ``registry`` overrides the default model registry.
+    ``c_values`` overrides the legal-c enumeration for 2.5D variants (e.g.
+    the replication factors a device pool can actually realize);
+    ``registry`` overrides the default model registry.
     """
     return best_variant_batch(ctx, algo, [(n, p)], variants=variants,
                               r_values=r_values, max_c=max_c,
@@ -195,7 +201,7 @@ def best_variant(ctx: AlgoContext, algo: str, n: int, p: int,
 
 
 def select(ctx: AlgoContext, algo: str, n: int, p: int, **kw) -> VariantChoice:
-    """The tuner entry point: the single fastest variant for the scenario.
+    """The single fastest variant for the scenario.
 
     Raises ValueError when every requested variant is memory-infeasible
     (only possible with pinned ``c_values``)."""
@@ -239,7 +245,7 @@ def prediction_table(ctx: AlgoContext, algo: str,
 
 
 def format_table(table, algo: str, registry=None) -> str:
-    variants = (registry or _registry()).variants(algo)
+    variants = (registry or DEFAULT_REGISTRY).variants(algo)
     lines = [f"# predicted %-of-peak — {algo}"]
     for n, by_cores in table.items():
         lines.append(f"  size n={n}")
@@ -269,7 +275,7 @@ def crossover_core_count(ctx: AlgoContext, algo: str, n: int,
     are skipped rather than KeyError'd.  One batched model evaluation per
     variant covers the whole core-count range.
     """
-    reg = registry or _registry()
+    reg = registry or DEFAULT_REGISTRY
     wanted = ("2d_ovlp", "2.5d_ovlp")
     have = reg.variants(algo)
     if any(v not in have for v in wanted):

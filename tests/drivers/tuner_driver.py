@@ -13,8 +13,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro import linalg  # noqa: E402
-from repro.core import predictor  # noqa: E402
-from repro.tuner import PlanCache, Tuner, feasible_grids  # noqa: E402
+from repro.tuner import PlanCache, Tuner, feasible_grids, select  # noqa: E402
 
 
 def _rel_err(got, ref):
@@ -66,7 +65,7 @@ def main():
     out["fresh_tuner_model_evals"] = fresh.stats["model_evals"]
     out["fresh_tuner_disk_hits"] = fresh.cache.disk_hits
 
-    # the dispatched variant equals predictor.select over the same
+    # the dispatched variant equals select.select over the same
     # realizable configurations
     plan = tuner.plan("matmul", n, devices=devices)
     ctx = tuner.registry.context(plan.machine)
@@ -76,8 +75,8 @@ def main():
             kind = "2d" if c == 1 else "2.5d"
             variants = [v for v in tuner.registry.variants(algo)
                         if v.startswith(kind)]
-            ch = predictor.select(ctx, algo, n, p, variants=variants,
-                                  c_values=[c], r_values=(1,))
+            ch = select.select(ctx, algo, n, p, variants=variants,
+                               c_values=[c], r_values=(1,))
             if best is None or ch.result.total < best[0].result.total:
                 best = (ch, algo)
     out["plan_matches_select"] = bool(best[1] == plan.algo and

@@ -6,11 +6,13 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (HOPPER, AlgoContext, CommModel, ComputeModel,
-                        IdentityCalibration, ParametricCalibration, evaluate,
-                        pct_of_peak)
-from repro.core.algorithms import ALGOS, MODELS, USEFUL_FLOPS, VARIANTS
+                        IdentityCalibration, ParametricCalibration)
 from repro.core.perfmodel import HOPPER_EFFICIENCY
-from repro.core.predictor import best_variant, legal_c_values, select
+from repro.perf import ALGOS, VARIANTS, pct_of_peak
+from repro.tuner import DEFAULT_REGISTRY
+from repro.tuner.select import best_variant, legal_c_values, select
+
+evaluate = DEFAULT_REGISTRY.evaluate
 
 CTX = AlgoContext(CommModel(HOPPER, ParametricCalibration()),
                   ComputeModel(HOPPER, HOPPER_EFFICIENCY))

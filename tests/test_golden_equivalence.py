@@ -17,9 +17,12 @@ import numpy as np
 import pytest
 
 from repro.core import (HOPPER, AlgoContext, CommModel, ComputeModel,
-                        IdentityCalibration, ParametricCalibration, evaluate)
+                        IdentityCalibration, ParametricCalibration)
 from repro.core.perfmodel import HOPPER_EFFICIENCY
 from repro.perf import PROGRAMS, evaluate_program
+from repro.tuner import DEFAULT_REGISTRY
+
+evaluate = DEFAULT_REGISTRY.evaluate
 
 GOLD_PATH = os.path.join(os.path.dirname(__file__), "golden",
                          "model_values.json")
@@ -44,8 +47,8 @@ KEYS = sorted({(e["algo"], e["variant"]) for e in ENTRIES})
 
 @pytest.mark.parametrize("algo,variant", KEYS)
 def test_scalar_matches_golden(algo, variant):
-    """The scalar shim (IR program, 0-d env) reproduces every golden cell:
-    totals, ledgers, and each named term."""
+    """The scalar evaluation (IR program, 0-d env) reproduces every golden
+    cell: totals, ledgers, and each named term."""
     for e in ENTRIES:
         if (e["algo"], e["variant"]) != (algo, variant):
             continue

@@ -10,14 +10,15 @@ import numpy as np
 import pytest
 
 from repro.core import (HOPPER, AlgoContext, CommModel, ComputeModel,
-                        IdentityCalibration, ParametricCalibration, evaluate,
-                        pct_of_peak)
+                        IdentityCalibration, ParametricCalibration)
 from repro.core.perfmodel import HOPPER_EFFICIENCY
-from repro.core import predictor
 from repro.perf import (Collective, Compute, EvalOptions, Loop, N, Overlap,
                         P, P2P, PROGRAMS, Program, Seq, SyncP2P, T,
                         evaluate_program, sqrt)
 from repro.tuner import DEFAULT_REGISTRY, PlanCache, Tuner
+from repro.tuner import select
+
+evaluate = DEFAULT_REGISTRY.evaluate
 
 CTX = AlgoContext(CommModel(HOPPER, ParametricCalibration()),
                   ComputeModel(HOPPER, HOPPER_EFFICIENCY))
@@ -120,7 +121,7 @@ class TestEvalOptions:
 
 class TestLU:
     """LU 2D/2.5D: authored as <50-line IR programs, registered and
-    tunable end-to-end with zero predictor/tuner changes."""
+    tunable end-to-end with zero selection/tuner changes."""
 
     def test_registered(self):
         assert "lu" in DEFAULT_REGISTRY.algos()
@@ -146,9 +147,9 @@ class TestLU:
         assert lu > ch
 
     def test_selectable_by_predictor(self):
-        ch = predictor.best_variant(CTX, "lu", 32768, 1024)
+        ch = select.best_variant(CTX, "lu", 32768, 1024)
         assert set(ch) == {"2d", "2.5d"}
-        best = predictor.select(CTX, "lu", 32768, 1024)
+        best = select.select(CTX, "lu", 32768, 1024)
         assert best.result.total == min(c.result.total for c in ch.values())
         assert 0 < best.pct_peak <= 100
 
@@ -165,8 +166,8 @@ class TestLU:
 
     def test_crossover_tolerates_missing_ovlp_variants(self):
         """lu has no *_ovlp models: crossover must return None, not KeyError
-        (the satellite fix for predictor.crossover_core_count)."""
-        assert predictor.crossover_core_count(
+        (the satellite fix for select.crossover_core_count)."""
+        assert select.crossover_core_count(
             CTX, "lu", 32768, [1536, 24576]) is None
 
 
@@ -174,23 +175,23 @@ class TestPredictorMissingVariants:
     def test_format_table_tolerates_dropped_variant(self):
         """A cell whose 2.5D variants were dropped (memory-infeasible under
         pinned c_values) renders as a dash, not a KeyError."""
-        tbl = predictor.prediction_table(CTX, "cannon", [262144], [1536],
-                                         c_values=[64])
+        tbl = select.prediction_table(CTX, "cannon", [262144], [1536],
+                                      c_values=[64])
         row = tbl[262144][1536]
         assert "2.5d" not in row          # dropped: 64-way replication OOMs
-        out = predictor.format_table(tbl, "cannon")
+        out = select.format_table(tbl, "cannon")
         assert "—" in out and "2d" in out
 
     def test_crossover_skips_infeasible_cells(self):
         """With pinned c_values making 2.5D infeasible at low p, crossover
         scans past those cells instead of KeyError'ing."""
         cores = [1536, 6144, 24576, 98304, 393216]
-        cx = predictor.crossover_core_count(CTX, "cannon", 32768, cores)
+        cx = select.crossover_core_count(CTX, "cannon", 32768, cores)
         # same answer as comparing the two tuned variants cell by cell
         want = None
         for co in cores:
             p = max(1, co // HOPPER.threads_per_unit)
-            ch = predictor.best_variant(CTX, "cannon", 32768, p)
+            ch = select.best_variant(CTX, "cannon", 32768, p)
             if ch["2.5d_ovlp"].result.total < ch["2d_ovlp"].result.total:
                 want = co
                 break
@@ -198,9 +199,9 @@ class TestPredictorMissingVariants:
 
     def test_batched_best_variant_equals_per_cell(self):
         cells = [(16384, 256), (32768, 1024), (65536, 4096)]
-        batch = predictor.best_variant_batch(CTX, "trsm", cells)
+        batch = select.best_variant_batch(CTX, "trsm", cells)
         for cell, got in zip(cells, batch):
-            solo = predictor.best_variant(CTX, "trsm", *cell)
+            solo = select.best_variant(CTX, "trsm", *cell)
             assert set(got) == set(solo)
             for v in got:
                 assert got[v].result.total == pytest.approx(

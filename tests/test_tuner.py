@@ -9,39 +9,40 @@ import sys
 
 import pytest
 
-from repro.core import predictor
 from repro.core.machine import CPU_HOST, HOPPER
+from repro.perf import PROGRAMS, evaluate_program
 from repro.tuner import (DEFAULT_REGISTRY, ExecutionPlan, PerfModelRegistry,
                          PlanCache, Tuner, feasible_grids, machine_fingerprint,
-                         machine_for_platform, plan_key)
+                         machine_for_platform, plan_key, select)
 
 HERE = os.path.dirname(__file__)
 
 
 class TestRegistry:
     def test_unifies_algorithm_models(self):
-        from repro.core.algorithms import MODELS
-        assert set(DEFAULT_REGISTRY.algos()) == {a for a, _ in MODELS}
+        assert set(DEFAULT_REGISTRY.algos()) == {a for a, _ in PROGRAMS}
         for algo in DEFAULT_REGISTRY.algos():
             assert set(DEFAULT_REGISTRY.variants(algo)) == \
-                {v for a, v in MODELS if a == algo}
+                {v for a, v in PROGRAMS if a == algo}
 
     def test_evaluate_matches_core(self):
-        from repro.core.algorithms import evaluate
         ctx = DEFAULT_REGISTRY.context(HOPPER.name)
         r1 = DEFAULT_REGISTRY.evaluate(ctx, "cannon", "2.5d", 32768, 1024, c=4)
-        r2 = evaluate(ctx, "cannon", "2.5d", 32768, 1024, c=4)
-        assert r1.total == r2.total
+        r2 = evaluate_program(PROGRAMS[("cannon", "2.5d")], ctx, 32768, 1024,
+                              c=4)
+        assert r1.total == float(r2.total)
+        assert r1.comm == float(r2.comm) and r1.comp == float(r2.comp)
+        assert (r1.algo, r1.variant, r1.c) == ("cannon", "2.5d", 4)
 
     def test_duplicate_registration_raises(self):
         reg = PerfModelRegistry()
-        reg.register_algorithm("x", "2d", lambda *a, **k: None)
+        reg.register_program(PROGRAMS[("cannon", "2d")])
         with pytest.raises(ValueError):
-            reg.register_algorithm("x", "2d", lambda *a, **k: None)
+            reg.register_program(PROGRAMS[("cannon", "2d")])
 
     def test_unknown_keys_raise_helpfully(self):
         with pytest.raises(KeyError, match="registered"):
-            DEFAULT_REGISTRY.model("cannon", "3d")
+            DEFAULT_REGISTRY.program("cannon", "3d")
         with pytest.raises(KeyError, match="registered"):
             DEFAULT_REGISTRY.machine("cray-ymp")
 
@@ -63,13 +64,13 @@ class TestRegistry:
 class TestLegalCValues:
     def test_no_silent_fallback(self):
         # p=2 (cap < 2) and p=6 (p/c never square) have no legal factor
-        assert predictor.legal_c_values(2) == []
-        assert predictor.legal_c_values(6) == []
+        assert select.legal_c_values(2) == []
+        assert select.legal_c_values(6) == []
 
     def test_legal_factors_are_legal(self):
         import math
         for p in (64, 256, 1024, 4096):
-            for c in predictor.legal_c_values(p):
+            for c in select.legal_c_values(p):
                 g = math.sqrt(p / c)
                 assert abs(g - round(g)) < 1e-9
 
@@ -93,13 +94,13 @@ class TestFeasibleGrids:
 class TestPlanning:
     def test_variant_matches_predictor_select(self, tmp_path):
         # 4 devices: the only realizable grid is 2x2 (p=4, c=1), so the
-        # dispatcher's choice must equal predictor.select over 2D variants.
+        # dispatcher's choice must equal select.select over 2D variants.
         t = Tuner(cache=PlanCache(str(tmp_path)))
         for algo in ("cholesky", "trsm", "summa"):
             plan = t.plan(algo, 8192, device_count=4, platform="cpu",
                           device_kind="test-cpu")
             ctx = t.registry.context("cpu-host")
-            ch = predictor.select(ctx, algo, 8192, 4,
+            ch = select.select(ctx, algo, 8192, 4,
                                   variants=("2d", "2d_ovlp"), r_values=(1,))
             assert plan.p == 4 and plan.c == 1
             assert plan.variant == ch.result.variant
